@@ -33,7 +33,7 @@ class ThreadContract:
     handoffs: dict[str, str] = dataclasses.field(default_factory=dict)
 
 
-# the observability recording/span-assembly scopes run INSIDE the
+# the observability recording/span scopes run INSIDE the
 # round's finish/actuate window and the express fast path: hot under
 # BOTH PTA001 (no host sync) and PTA002 (no O(cluster) walk) from day
 # one — one constant referenced from both maps so the two enforcement
@@ -106,10 +106,12 @@ _OBS_HOT_SCOPES = {
         "SchedulerMetrics.record_overload_cleared",
     ),
     "poseidon_tpu/obs/spans.py": (
-        "round_span_tree",
-        "express_span_tree",
-        "stream_span_tree",
-        "emit_span",
+        # the profiler span around every host phase: a clock read, a
+        # float add into a stats field and the annotation — never a
+        # device sync
+        "span.__init__",
+        "span.__enter__",
+        "span.__exit__",
     ),
 }
 
@@ -236,6 +238,8 @@ DEFAULT_CONTRACTS = Contracts(
         "poseidon_tpu/bridge/bridge.py": (
             "SchedulerBridge.begin_round",
             "SchedulerBridge.finish_round",
+            "SchedulerBridge._finish",
+            "SchedulerBridge._apply",
             "SchedulerBridge.express_batch",
             "SchedulerBridge.stream_window",
             "SchedulerBridge.stream_flush",
@@ -334,6 +338,8 @@ DEFAULT_CONTRACTS = Contracts(
         "poseidon_tpu/bridge/bridge.py": (
             "SchedulerBridge.begin_round",
             "SchedulerBridge.finish_round",
+            "SchedulerBridge._finish",
+            "SchedulerBridge._apply",
             "SchedulerBridge.express_batch",
             "SchedulerBridge.stream_window",
             "SchedulerBridge.stream_flush",

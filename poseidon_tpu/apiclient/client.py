@@ -46,6 +46,7 @@ import urllib.request
 from typing import Callable
 
 from poseidon_tpu.cluster import Machine, Task, TaskPhase
+from poseidon_tpu.obs.spans import span
 
 log = logging.getLogger(__name__)
 
@@ -274,7 +275,8 @@ class K8sApiClient:
         All pages of one logical list are fetched before parsing; a page
         failure (after per-request retries) raises so the caller never
         sees a silently truncated snapshot — the bridge would read the
-        missing tail as mass deletion.
+        missing tail as mass deletion. Each page (request and JSON
+        decode) is a ``poseidon/list_page`` profiler span.
         """
         items: list[dict] = []
         token = ""
@@ -294,7 +296,8 @@ class K8sApiClient:
             path = resource
             if params:
                 path += "?" + urllib.parse.urlencode(params)
-            doc = self._request(path)
+            with span("list_page", resource=resource):
+                doc = self._request(path)
             items.extend(doc.get("items", []))
             meta = doc.get("metadata", {})
             try:

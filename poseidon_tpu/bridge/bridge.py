@@ -118,12 +118,7 @@ from poseidon_tpu.ops.resident import (
     ResidentSolver,
 )
 from poseidon_tpu.obs.metrics import STORM_RESYNCS, STORM_WINDOW
-from poseidon_tpu.obs.spans import (
-    emit_span,
-    express_span_tree,
-    round_span_tree,
-    stream_span_tree,
-)
+from poseidon_tpu.obs.spans import span
 from poseidon_tpu.ops.transport import topology_from_columns
 from poseidon_tpu.trace import TraceGenerator
 
@@ -157,6 +152,21 @@ class SchedulerStats:
     already absorbed), ``overlap_ms`` (wall time between
     begin and finish, i.e. how much host work was hidden), and
     ``wall_ms`` (begin start to finish end, the round's wall span).
+
+    The phases outside the bridge, each measured by its ``poseidon/``
+    span (obs/spans.py): ``list_ms`` is the apiserver LIST time (poll
+    LISTs or ``watcher.tick()``, HTTP and JSON decode) since the
+    previous round began, moved into stats at begin like
+    ``observe_ms``; ``post_ms``/``posts`` are the actuation POSTs the
+    daemon loop issued (bindings, evictions, migrations: their wall time
+    and their count) since the previous round's stats were emitted — in the
+    pipelined loop the previous round's POSTs, made in this round's
+    overlap window — moved into stats when this round's are emitted.
+    ``finish_ms`` is ``finish_round``'s apply phase (delta extraction
+    and the per-pod events, logs and lifecycle stamps, after the join).
+    ``placed_wait_rounds`` counts, over the pods this round placed, the
+    rounds each had waited (the sum of their ``wait_rounds``): divided
+    by ``pods_placed`` it is the mean queueing in rounds.
     """
 
     round_num: int = 0
@@ -237,6 +247,11 @@ class SchedulerStats:
     fetch_wait_ms: float = 0.0
     overlap_ms: float = 0.0
     wall_ms: float = 0.0
+    list_ms: float = 0.0
+    post_ms: float = 0.0
+    posts: int = 0
+    finish_ms: float = 0.0
+    placed_wait_rounds: int = 0
 
 
 @dataclasses.dataclass
@@ -310,7 +325,6 @@ class SchedulerBridge:
         stream_windows: int = 0,
         shrink_grace_s: float = SHRINK_GRACE_S,
         metrics=None,
-        profile_spans: bool = False,
         solver=None,
         flightrec=None,
         lifecycle=None,
@@ -327,13 +341,11 @@ class SchedulerBridge:
         # observability: ``metrics`` is an obs.SchedulerMetrics (or
         # None); recording happens ONLY at finish/actuate time from
         # host values this class already holds — no new device syncs
-        # (the recording helpers are PTA001/PTA002 scopes).
-        # ``profile_spans`` emits a SPAN trace event per round/express
-        # batch (--trace_profile); ``lane`` is a driver-set label
-        # (poll / watch / +pipelined / express ...) stamped onto each
-        # round's stats for the metrics/report grouping.
+        # (the recording helpers are PTA001/PTA002 scopes). ``lane``
+        # is a label the loop sets (poll / watch / +pipelined / express
+        # ...), stamped onto each round's stats for the metrics/report
+        # grouping.
         self.metrics = metrics
-        self.profile_spans = profile_spans
         # the anomaly flight recorder (obs/flightrec.py, --flight_
         # recorder): captures each round's full host-side inputs at
         # begin time and dumps the ring on DEGRADE / EXPRESS_DEGRADE /
@@ -406,6 +418,13 @@ class SchedulerBridge:
         # per-round accumulators surfaced in SchedulerStats: observe
         # host time and watch degradation counts since the last round
         self._observe_ms = 0.0
+        # accumulators the cli loop feeds (its poseidon/list and
+        # poseidon/post_bindings spans add here; SchedulerStats
+        # documents the windows): LIST ms, moved into stats at begin;
+        # actuation POST ms and count, moved in when stats are emitted
+        self.pending_list_ms = 0.0
+        self.pending_post_ms = 0.0
+        self.pending_posts = 0
         self._watch_resyncs = 0
         self._watch_reconnects = 0
         # consecutive implausible-shrink polls (mass-eviction guard),
@@ -625,12 +644,12 @@ class SchedulerBridge:
 
     def observe_nodes(self, nodes: list[Machine]) -> None:
         """Upsert machines; release the ones that disappeared."""
-        t0 = time.perf_counter()
         # a snapshot diff can move anything (and node changes reshape
         # the machine axis): the on-HBM express context cannot follow
         if self.express_lane:
             self.solver.invalidate_express()
-        try:
+        with span("observe", self, "_observe_ms",
+                  round=self.round_num + 1):
             known_before = len(self.machines)
             known_names = set(self.machines)
             seen = set()
@@ -643,8 +662,6 @@ class SchedulerBridge:
                 return
             for name in gone:
                 self._remove_node(name)
-        finally:
-            self._observe_ms += (time.perf_counter() - t0) * 1000
 
     def observe_node_event(
         self, type_: str, node: Machine
@@ -656,16 +673,14 @@ class SchedulerBridge:
         defends against *truncated snapshots*, and an event stream
         never infers deletion from absence (resyncs go back through
         ``observe_nodes`` and get the guard)."""
-        t0 = time.perf_counter()
         if self.express_lane:
             self.solver.invalidate_express()
-        try:
+        with span("observe", self, "_observe_ms",
+                  round=self.round_num + 1):
             if type_ == "DELETED":
                 self._remove_node(node.name)
             else:
                 self._upsert_node(node)
-        finally:
-            self._observe_ms += (time.perf_counter() - t0) * 1000
 
     def _pending_reobserved(
         self, known: Task, pod: Task, stored: Task
@@ -840,10 +855,10 @@ class SchedulerBridge:
     def observe_pods(self, pods: list[Task]) -> None:
         """The reference's per-pod dispatch (scheduler_bridge.cc:132-162),
         with restart reconcile and terminal-state retirement."""
-        t0 = time.perf_counter()
         if self.express_lane:
             self.solver.invalidate_express()
-        try:
+        with span("observe", self, "_observe_ms",
+                  round=self.round_num + 1):
             known_before = len(self.tasks)
             known_uids = set(self.tasks)
             seen = set()
@@ -856,8 +871,6 @@ class SchedulerBridge:
                 return
             for uid in gone:
                 self._remove_pod(uid)
-        finally:
-            self._observe_ms += (time.perf_counter() - t0) * 1000
 
     def observe_pod_event(self, type_: str, pod: Task) -> None:
         """Event-driven observe: one typed pod event from the watch
@@ -866,14 +879,12 @@ class SchedulerBridge:
         DELETED retires the pod like a poll disappearance. Explicit
         deletions bypass the mass-eviction guard by design — see
         ``observe_node_event``."""
-        t0 = time.perf_counter()
-        try:
+        with span("observe", self, "_observe_ms",
+                  round=self.round_num + 1):
             if type_ == "DELETED":
                 self._remove_pod(pod.uid)
             else:
                 self._upsert_pod(pod)
-        finally:
-            self._observe_ms += (time.perf_counter() - t0) * 1000
 
     def note_watch_activity(
         self, resyncs: int = 0, reconnects: int = 0
@@ -1157,12 +1168,6 @@ class SchedulerBridge:
             self._express_e2b.append(e2b)
             e2b_samples.append(e2b)
         self._express_places += len(bindings)
-        if self.profile_spans:
-            emit_span(
-                self.trace,
-                express_span_tree(latency, outcome.timings),
-                self.round_num,
-            )
         self.trace.flush()
         if self.metrics is not None:
             self.metrics.record_express_batch(e2b_samples)
@@ -1358,14 +1363,6 @@ class SchedulerBridge:
                 "failed_window": out.failed_window,
             },
         )
-        if self.profile_spans:
-            emit_span(
-                self.trace,
-                stream_span_tree(
-                    latency, out.timings, windows=out.windows,
-                ),
-                self.round_num,
-            )
         if not out.ok:
             # the solver already invalidated the context; the good
             # windows above are bound, the failed window's events
@@ -1491,6 +1488,8 @@ class SchedulerBridge:
         stats.degrades_total = self._degrades_total
         stats.observe_ms = round(self._observe_ms, 3)
         self._observe_ms = 0.0
+        stats.list_ms = round(self.pending_list_ms, 3)
+        self.pending_list_ms = 0.0
         stats.watch_resyncs = self._watch_resyncs
         self._watch_resyncs = 0
         stats.watch_reconnects = self._watch_reconnects
@@ -1598,6 +1597,7 @@ class SchedulerBridge:
             self._express_placed.clear()
             stats.total_ms = (time.perf_counter() - t_start) * 1000
             stats.wall_ms = stats.total_ms
+            self._take_posts(stats)
             self.trace.emit(
                 "ROUND", round_num=self.round_num,
                 detail=dataclasses.asdict(stats),
@@ -1614,24 +1614,23 @@ class SchedulerBridge:
                                    unscheduled=[]),
             )
 
-        t0 = time.perf_counter()
         topology = None
-        if self._graph is not None:
-            arrays, meta = self._graph.build_arrays(cluster, pending)
-            stats.build_mode = self._graph.last_build_mode
-            cols = self._graph.columns
-            topology = topology_from_columns(cols)
-            cpu_col, mem_col = self._graph.cost_columns()
-        else:
-            fb = FlowGraphBuilder(
-                preemption=self.enable_preemption,
-                migration_hysteresis=self.migration_hysteresis,
-            )
-            cols = fb.merge_columns(fb.extract_columns(cluster))
-            arrays, meta = fb.assemble(cols)
-            stats.build_mode = "legacy"
-            cpu_col, mem_col = cols.cpu_milli, cols.mem_kb
-        stats.build_ms = (time.perf_counter() - t0) * 1000
+        with span("build", stats, "build_ms", round=self.round_num):
+            if self._graph is not None:
+                arrays, meta = self._graph.build_arrays(cluster, pending)
+                stats.build_mode = self._graph.last_build_mode
+                cols = self._graph.columns
+                topology = topology_from_columns(cols)
+                cpu_col, mem_col = self._graph.cost_columns()
+            else:
+                fb = FlowGraphBuilder(
+                    preemption=self.enable_preemption,
+                    migration_hysteresis=self.migration_hysteresis,
+                )
+                cols = fb.merge_columns(fb.extract_columns(cluster))
+                arrays, meta = fb.assemble(cols)
+                stats.build_mode = "legacy"
+                cpu_col, mem_col = cols.cpu_milli, cols.mem_kb
 
         machine_names = meta.machine_names
         cost_kwargs = dict(
@@ -1661,15 +1660,14 @@ class SchedulerBridge:
                 if cur is not None
                 else np.zeros(len(machine_names), np.int32)
             )
-        t0 = time.perf_counter()
-        solve = self.solver.begin_round(
-            arrays, meta,
-            cost_model=self.cost_model,
-            topology=topology,
-            cost_input_kwargs=cost_kwargs,
-        )
+        with span("dispatch", stats, "dispatch_ms", round=self.round_num):
+            solve = self.solver.begin_round(
+                arrays, meta,
+                cost_model=self.cost_model,
+                topology=topology,
+                cost_input_kwargs=cost_kwargs,
+            )
         t_end = time.perf_counter()
-        stats.dispatch_ms = (t_end - t0) * 1000
         ir = InflightRound(
             stats=stats,
             solve=solve,
@@ -1736,13 +1734,16 @@ class SchedulerBridge:
         if self._inflight is not ir:
             raise RuntimeError("finish_round() got a stale round")
         self._inflight = None
+        with span("finish", round=ir.stats.round_num):
+            return self._finish(ir)
+
+    def _finish(self, ir: InflightRound) -> RoundResult:
+        """``finish_round``'s body, inside its ``poseidon/finish``
+        span: the join (the solver's ``poseidon/fetch_wait``), the
+        apply phase, then the round's stats and trace record."""
         stats = ir.stats
         t_fin = time.perf_counter()
         stats.overlap_ms = (t_fin - ir.t_begin_end) * 1000
-
-        # span stamps on the monotonic clock (trace.py clock contract:
-        # wall time is for timestamps only, never durations)
-        t_join0 = time.monotonic()
         try:
             outcome = self.solver.finish_round(ir.solve)
         except FetchTimeout as e:
@@ -1761,8 +1762,6 @@ class SchedulerBridge:
             self.trace.flush()
             self.flight_dump("fetch-timeout", label=str(e))
             raise
-        t_join1 = time.monotonic()
-        meta = ir.meta
         # a finished round replaces the express context: whatever
         # retire backlog / unconfirmed set the OLD window accumulated
         # is stale against the new round's rows (stream stamps too —
@@ -1806,7 +1805,55 @@ class SchedulerBridge:
                 # below, so the dump carries this round's result too
                 flight_dump_why = why
         stats.degrades_total = self._degrades_total
+        with span("apply", stats, "finish_ms", round=stats.round_num):
+            (bindings, unscheduled, unsched_ages, migrations, preemptions,
+             dset) = self._apply(ir, outcome)
+        t_now = time.perf_counter()
+        stats.total_ms = ir.begin_ms + (t_now - t_fin) * 1000
+        stats.wall_ms = (t_now - ir.t_begin_start) * 1000
+        self._take_posts(stats)
+        self.trace.emit(
+            "ROUND", round_num=ir.stats.round_num,
+            detail=dataclasses.asdict(stats),
+        )
+        self.trace.flush()
+        if self.lifecycle is not None:
+            # the standing-unscheduled wait-age surface (the ages the
+            # _age walk in _apply already collected — no second walk)
+            self.lifecycle.note_unscheduled(unsched_ages)
+        if self.metrics is not None:
+            self.metrics.record_round(stats)
+            self._note_trace_drops()
+        if self.flightrec is not None:
+            self.flightrec.capture_finish(
+                ir.flight, outcome, dataclasses.asdict(stats),
+                extra={
+                    "unscheduled": list(unscheduled),
+                    "deferred": [d.task for d in dset.deferred],
+                },
+            )
+            if flight_dump_why:
+                self.flight_dump("degrade", label=flight_dump_why)
+        return RoundResult(
+            bindings=bindings, stats=stats, unscheduled=unscheduled,
+            migrations=migrations, preemptions=preemptions,
+        )
 
+    def _take_posts(self, stats: SchedulerStats) -> None:
+        """Move the loop's POST window into the stats being emitted."""
+        stats.post_ms = round(self.pending_post_ms, 3)
+        stats.posts = self.pending_posts
+        self.pending_post_ms = 0.0
+        self.pending_posts = 0
+
+    def _apply(self, ir: InflightRound, outcome):
+        """The apply phase (``poseidon/apply``, ``finish_ms``): typed
+        deltas from the solved assignment, then per pod its decision,
+        aging, trace event, log line and lifecycle stamp. Returns
+        ``(bindings, unscheduled, unsched_ages, migrations,
+        preemptions, dset)``."""
+        stats = ir.stats
+        meta = ir.meta
         # the decision layer: diff the solved assignment against current
         # placements into typed PLACE | MIGRATE | PREEMPT | NOOP records
         # (graph/deltas.py), budget-bounded in rebalancing mode. In
@@ -1873,6 +1920,7 @@ class SchedulerBridge:
                 _age(d.task, task)
                 continue
             bindings[d.task] = d.machine
+            stats.placed_wait_rounds += task.wait_rounds
             if self.lifecycle is not None:
                 self.lifecycle.stamp_decided(d.task, lc_lane)
             self.decision_log.append((
@@ -1968,45 +2016,8 @@ class SchedulerBridge:
                 # migration (seated at the solve's target, running at
                 # the old machine): express sits this window out
                 self.solver.invalidate_express()
-        t_now = time.perf_counter()
-        stats.total_ms = ir.begin_ms + (t_now - t_fin) * 1000
-        stats.wall_ms = (t_now - ir.t_begin_start) * 1000
-        if self.profile_spans:
-            emit_span(
-                self.trace,
-                round_span_tree(
-                    stats,
-                    join_ms=(t_join1 - t_join0) * 1000,
-                    actuate_ms=(time.monotonic() - t_join1) * 1000,
-                ),
-                ir.stats.round_num,
-            )
-        self.trace.emit(
-            "ROUND", round_num=ir.stats.round_num,
-            detail=dataclasses.asdict(stats),
-        )
-        self.trace.flush()
-        if self.lifecycle is not None:
-            # the standing-unscheduled wait-age surface (the ages the
-            # _age walk above already collected — no second walk)
-            self.lifecycle.note_unscheduled(unsched_ages)
-        if self.metrics is not None:
-            self.metrics.record_round(stats)
-            self._note_trace_drops()
-        if self.flightrec is not None:
-            self.flightrec.capture_finish(
-                ir.flight, outcome, dataclasses.asdict(stats),
-                extra={
-                    "unscheduled": list(unscheduled),
-                    "deferred": [d.task for d in dset.deferred],
-                },
-            )
-            if flight_dump_why:
-                self.flight_dump("degrade", label=flight_dump_why)
-        return RoundResult(
-            bindings=bindings, stats=stats, unscheduled=unscheduled,
-            migrations=migrations, preemptions=preemptions,
-        )
+        return (bindings, unscheduled, unsched_ages, migrations,
+                preemptions, dset)
 
     def cancel_round(self, ir: InflightRound | None = None) -> None:
         """Abandon an in-flight round (driver error path): join and

@@ -76,6 +76,7 @@ import time
 from poseidon_tpu.apiclient.client import ApiError, K8sApiClient
 from poseidon_tpu.bridge import SchedulerBridge
 from poseidon_tpu.models import COST_MODELS
+from poseidon_tpu.obs.spans import span
 
 log = logging.getLogger("poseidon_tpu.cli")
 
@@ -264,8 +265,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="append cluster-trace-style scheduler events "
                         "(SUBMIT/SCHEDULE/EVICT/FINISH/ROUND) here")
     # the operational surface (poseidon_tpu/obs/): a daemon-thread HTTP
-    # server exposing Prometheus metrics + health, and per-phase span
-    # profiling into the trace stream
+    # server exposing Prometheus metrics + health (and /debug/profile,
+    # a profiler capture of the poseidon/ spans beside the device ops)
     p.add_argument("--metrics_port", type=int, default=0,
                    help="serve /metrics (Prometheus text format), "
                         "/healthz (liveness) and /readyz (ready = seed "
@@ -277,12 +278,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "(the endpoint is unauthenticated: bind "
                         "127.0.0.1 or the pod IP on hosts with "
                         "untrusted interfaces)")
-    p.add_argument("--trace_profile",
-                   default="false", choices=["true", "false"],
-                   help="emit a SPAN phase-span tree per round and per "
-                        "express batch into the trace stream (inspect "
-                        "with python -m poseidon_tpu.trace report / "
-                        "chrome)")
     # the decision-evidence layer (README "Explain & replay"): the
     # anomaly flight recorder keeps the last K rounds' full solve
     # inputs in a bounded ring and dumps .npz + JSON on DEGRADE /
@@ -469,8 +464,18 @@ def parse_args(argv: list[str]) -> argparse.Namespace:
     return args
 
 
+def _post_span(bridge, posts: int, round_num: int) -> span:
+    """The ``poseidon/post_bindings`` span around one batch of
+    actuation POSTs (and their journal/lifecycle marks): its time and
+    its ``posts`` land in the bridge's POST window, which the next
+    emitted ``SchedulerStats`` takes as ``post_ms``/``posts``."""
+    bridge.pending_posts += posts
+    return span("post_bindings", bridge, "pending_post_ms",
+                round=round_num)
+
+
 def _post_bindings(client, bridge, bindings: dict[str, str],
-                   journal=None, seqs=None, outbox=None):
+                   journal=None, seqs=None, outbox=None, round_num=0):
     """POST bindings concurrently (bounded): serially, a 10k-placement
     round is 10k sequential HTTP round trips — the reference has the
     same flaw (one pplx chain joined per pod, k8s_api_client.cc:225).
@@ -483,23 +488,26 @@ def _post_bindings(client, bridge, bindings: dict[str, str],
     call, that ordering is the crash-consistency contract. With an
     ``outbox``, unreachable POSTs park there (outcome "parked"): the
     pod stays confirmed, the journal intent stays open, and the
-    outbox pump owns the retries — the apiserver-outage ladder."""
+    outbox pump owns the retries — the apiserver-outage ladder. Each
+    POST is a ``poseidon/bind_post`` span on its worker thread, with
+    ``round_num`` (the round that decided the bindings) as its id."""
     import concurrent.futures as _cf
 
     def _bind(item):
         uid, machine = item
         task = bridge.tasks.get(uid)
         ns = task.namespace if task else "default"
-        outcome = client.bind_outcome(uid, machine, namespace=ns)
-        if outcome == "ok" and journal is not None and seqs:
-            journal.posted(seqs.get(("bind", uid), 0))
-        if outcome == "unreachable" and outbox is not None:
-            outbox.enqueue(
-                "bind", uid, machine=machine,
-                seq=(seqs or {}).get(("bind", uid), 0),
-                round_num=bridge.round_num,
-            )
-            outcome = "parked"
+        with span("bind_post", round=round_num):
+            outcome = client.bind_outcome(uid, machine, namespace=ns)
+            if outcome == "ok" and journal is not None and seqs:
+                journal.posted(seqs.get(("bind", uid), 0))
+            if outcome == "unreachable" and outbox is not None:
+                outbox.enqueue(
+                    "bind", uid, machine=machine,
+                    seq=(seqs or {}).get(("bind", uid), 0),
+                    round_num=bridge.round_num,
+                )
+                outcome = "parked"
         return uid, machine, outcome
 
     workers = min(16, len(bindings))
@@ -509,7 +517,7 @@ def _post_bindings(client, bridge, bindings: dict[str, str],
 
 def _actuate_rebalance(client, bridge, migrations, preemptions, *,
                        confirm: bool, journal=None, seqs=None,
-                       outbox=None):
+                       outbox=None, round_num=0):
     """Actuate MIGRATE (evict + re-bind) and PREEMPT (evict) deltas.
 
     ``confirm=True`` is the serial contract (state changes only after
@@ -520,7 +528,8 @@ def _actuate_rebalance(client, bridge, migrations, preemptions, *,
     posted/confirmed/failed per delta. With an ``outbox``, unreachable
     POSTs park there (the decision stands, only the wire is broken):
     the pod keeps its confirmed state, the journal intent stays open,
-    and the pump replays idempotently.
+    and the pump replays idempotently. One ``poseidon/post_bindings``
+    span covers the batch (``_post_span``).
     """
     def _ns(uid):
         task = bridge.tasks.get(uid)
@@ -530,47 +539,48 @@ def _actuate_rebalance(client, bridge, migrations, preemptions, *,
         if journal is not None and seqs:
             getattr(journal, phase)(seqs.get((kind, uid), 0))
 
-    for uid, frm in preemptions.items():
-        out = client.evict_outcome(uid, namespace=_ns(uid))
-        if out == "ok":
-            _mark("evict", uid, "posted")
-            if confirm:
-                bridge.confirm_preemption(uid)
-            _mark("evict", uid, "confirmed")
-        elif out == "unreachable" and outbox is not None:
-            if confirm:
-                bridge.confirm_preemption(uid)
-            outbox.enqueue(
-                "evict", uid, from_machine=frm,
-                seq=(seqs or {}).get(("evict", uid), 0),
-                round_num=bridge.round_num,
-            )
-        else:
-            log.warning("eviction POST failed for %s; restoring", uid)
-            _mark("evict", uid, "failed")
-            bridge.restore_running(uid, frm)
-    for uid, (frm, to) in migrations.items():
-        ns = _ns(uid)
-        out = client.evict_outcome(uid, namespace=ns)
-        if out == "ok":
-            out = client.bind_outcome(uid, to, namespace=ns)
-        if out == "ok":
-            _mark("migrate", uid, "posted")
-            if confirm:
-                bridge.confirm_migration(uid, to)
-            _mark("migrate", uid, "confirmed")
-        elif out == "unreachable" and outbox is not None:
-            if confirm:
-                bridge.confirm_migration(uid, to)
-            outbox.enqueue(
-                "migrate", uid, machine=to, from_machine=frm,
-                seq=(seqs or {}).get(("migrate", uid), 0),
-                round_num=bridge.round_num,
-            )
-        else:
-            log.warning("migration POSTs failed for %s; restoring", uid)
-            _mark("migrate", uid, "failed")
-            bridge.restore_running(uid, frm)
+    with _post_span(bridge, len(migrations) + len(preemptions), round_num):
+        for uid, frm in preemptions.items():
+            out = client.evict_outcome(uid, namespace=_ns(uid))
+            if out == "ok":
+                _mark("evict", uid, "posted")
+                if confirm:
+                    bridge.confirm_preemption(uid)
+                _mark("evict", uid, "confirmed")
+            elif out == "unreachable" and outbox is not None:
+                if confirm:
+                    bridge.confirm_preemption(uid)
+                outbox.enqueue(
+                    "evict", uid, from_machine=frm,
+                    seq=(seqs or {}).get(("evict", uid), 0),
+                    round_num=bridge.round_num,
+                )
+            else:
+                log.warning("eviction POST failed for %s; restoring", uid)
+                _mark("evict", uid, "failed")
+                bridge.restore_running(uid, frm)
+        for uid, (frm, to) in migrations.items():
+            ns = _ns(uid)
+            out = client.evict_outcome(uid, namespace=ns)
+            if out == "ok":
+                out = client.bind_outcome(uid, to, namespace=ns)
+            if out == "ok":
+                _mark("migrate", uid, "posted")
+                if confirm:
+                    bridge.confirm_migration(uid, to)
+                _mark("migrate", uid, "confirmed")
+            elif out == "unreachable" and outbox is not None:
+                if confirm:
+                    bridge.confirm_migration(uid, to)
+                outbox.enqueue(
+                    "migrate", uid, machine=to, from_machine=frm,
+                    seq=(seqs or {}).get(("migrate", uid), 0),
+                    round_num=bridge.round_num,
+                )
+            else:
+                log.warning("migration POSTs failed for %s; restoring", uid)
+                _mark("migrate", uid, "failed")
+                bridge.restore_running(uid, frm)
 
 
 def run_loop(
@@ -642,6 +652,7 @@ def run_loop(
         obs_server = ObsServer(
             sched_metrics.registry, health, port=args.metrics_port,
             host=args.metrics_host, build=binfo,
+            profile_dir=args.flight_dir,
         )
     flightrec = None
     if args.flight_recorder == "true":
@@ -702,7 +713,6 @@ def run_loop(
         stream_windows=args.stream_windows,
         shrink_grace_s=args.node_grace_s,
         metrics=sched_metrics,
-        profile_spans=args.trace_profile == "true",
         flightrec=flightrec,
         lifecycle=lifecycle,
         auditor=auditor,
@@ -934,10 +944,14 @@ def run_loop(
         (unless an outage is declared — then the loop keeps rounding
         from last-known state). Feeds the outage detector: every real
         apiserver interaction counts, success or transport failure."""
+        # the LISTs (HTTP + JSON decode) feed the next round's list_ms
+        listing = span("list", bridge, "pending_list_ms",
+                       round=bridge.round_num + 1)
         if watcher is None:
             try:
-                nodes = client.all_nodes()
-                pods = client.all_pods()
+                with listing:
+                    nodes = client.all_nodes()
+                    pods = client.all_pods()
             except ApiError as e:
                 log.error("poll failed, skipping tick: %s", e)
                 detector.note_failure()
@@ -947,7 +961,8 @@ def run_loop(
             bridge.observe_pods(pods)
             return True
         try:
-            delta = watcher.tick()
+            with listing:
+                delta = watcher.tick()
         except ApiError as e:
             log.error("watch sync failed, skipping tick: %s", e)
             detector.note_failure()
@@ -988,18 +1003,19 @@ def run_loop(
         so a restart replay closes the pre-crash timeline."""
         if journal is None or not bindings:
             return {}
-        seqs = journal.intents(
-            [{"op": "bind", "uid": u, "machine": m,
-              "t_event_us": (
-                  lifecycle.event_wall_us(u)
-                  if lifecycle is not None else 0
-              )}
-             for u, m in bindings.items()],
-            bridge.round_num,
-        )
-        if lifecycle is not None:
-            for uid in bindings:
-                lifecycle.stamp(uid, "journal")
+        with span("journal", round=bridge.round_num):
+            seqs = journal.intents(
+                [{"op": "bind", "uid": u, "machine": m,
+                  "t_event_us": (
+                      lifecycle.event_wall_us(u)
+                      if lifecycle is not None else 0
+                  )}
+                 for u, m in bindings.items()],
+                bridge.round_num,
+            )
+            if lifecycle is not None:
+                for uid in bindings:
+                    lifecycle.stamp(uid, "journal")
         return seqs
 
     def _rebal_seqs(migrations, preemptions) -> dict:
@@ -1012,7 +1028,8 @@ def run_loop(
             {"op": "migrate", "uid": u, "machine": to, "from": frm}
             for u, (frm, to) in migrations.items()
         ]
-        return journal.intents(ops, bridge.round_num)
+        with span("journal", round=bridge.round_num):
+            return journal.intents(ops, bridge.round_num)
 
     def _mark_bind(seqs, uid, outcome: str) -> None:
         """Journal/lifecycle marks for one pool result ("ok" /
@@ -1033,6 +1050,28 @@ def run_loop(
             (journal.confirmed if outcome == "ok"
              else journal.failed)(seq)
 
+    def _post_and_confirm(bindings, round_num, lane) -> None:
+        """Journal, then POST bindings not yet confirmed (serial rounds,
+        express batches): a landed POST confirms the pod, and so does a
+        parked one (the decision stands, the outbox owns the wire; a
+        dead-letter revokes and re-queues later); a rejected one
+        re-queues it."""
+        seqs = _bind_seqs(bindings)
+        with _post_span(bridge, len(bindings), round_num):
+            for uid, machine, outcome in _post_bindings(
+                client, bridge, bindings, journal=journal,
+                seqs=seqs, outbox=outbox, round_num=round_num,
+            ):
+                _mark_bind(seqs, uid, outcome)
+                if outcome in ("ok", "parked"):
+                    bridge.confirm_binding(uid, machine)
+                else:
+                    log.warning(
+                        "%s bind POST failed for %s; re-queueing",
+                        lane, uid,
+                    )
+                    bridge.binding_failed(uid)
+
     def _post_express(result) -> None:
         """POST one express batch's bindings; rejections re-queue (the
         bridge invalidates the context, so the next full round owns
@@ -1040,19 +1079,7 @@ def run_loop(
         confirmed."""
         if result is None or not result.bindings:
             return
-        seqs = _bind_seqs(result.bindings)
-        for uid, machine, outcome in _post_bindings(
-            client, bridge, result.bindings, journal=journal,
-            seqs=seqs, outbox=outbox,
-        ):
-            _mark_bind(seqs, uid, outcome)
-            if outcome in ("ok", "parked"):
-                bridge.confirm_binding(uid, machine)
-            else:
-                log.warning(
-                    "express bind POST failed for %s; re-queueing", uid
-                )
-                bridge.binding_failed(uid)
+        _post_and_confirm(result.bindings, bridge.round_num, "express")
 
     def _express_window(window_s: float) -> None:
         """The inter-tick express window: turn small watch-event
@@ -1182,6 +1209,7 @@ def run_loop(
     to_rebal: tuple[dict, dict] = ({}, {})
     to_post_seqs: dict = {}
     to_rebal_seqs: dict = {}
+    to_post_round = 0      # the round that decided the staged deltas
     # express-lane demotion state: full rounds become a periodic
     # correction pass (every --express_correction_rounds ticks) while
     # the express context is live; a dead context forces the round
@@ -1202,32 +1230,35 @@ def run_loop(
             stats_fh.write(json.dumps(vars(s)) + "\n")
             stats_fh.flush()
 
-    def _post_and_revoke(to_post, seqs):
+    def _post_and_revoke(to_post, seqs, round_num):
         """POST optimistically-confirmed bindings; rejections re-queue
         the pod as unscheduled (counted in SchedulerStats) so it is
         re-offered next round; unreachable POSTs park in the outbox
         (the pod stays confirmed — outage semantics)."""
-        for uid, machine, outcome in _post_bindings(
-            client, bridge, to_post, journal=journal, seqs=seqs,
-            outbox=outbox,
-        ):
-            _mark_bind(seqs, uid, outcome)
-            if outcome not in ("ok", "parked"):
-                log.warning("bind POST failed for %s; re-queueing", uid)
-                bridge.binding_failed(uid)
+        with _post_span(bridge, len(to_post), round_num):
+            for uid, machine, outcome in _post_bindings(
+                client, bridge, to_post, journal=journal, seqs=seqs,
+                outbox=outbox, round_num=round_num,
+            ):
+                _mark_bind(seqs, uid, outcome)
+                if outcome not in ("ok", "parked"):
+                    log.warning(
+                        "bind POST failed for %s; re-queueing", uid
+                    )
+                    bridge.binding_failed(uid)
 
     def _flush_pending():
         """POST any deltas still queued from the last finished round."""
         nonlocal to_post, to_rebal, to_post_seqs, to_rebal_seqs
         if to_post:
-            _post_and_revoke(to_post, to_post_seqs)
+            _post_and_revoke(to_post, to_post_seqs, to_post_round)
             to_post = {}
             to_post_seqs = {}
         if to_rebal[0] or to_rebal[1]:
             _actuate_rebalance(
                 client, bridge, to_rebal[0], to_rebal[1],
                 confirm=False, journal=journal, seqs=to_rebal_seqs,
-                outbox=outbox,
+                outbox=outbox, round_num=to_post_round,
             )
             to_rebal = ({}, {})
             to_rebal_seqs = {}
@@ -1238,7 +1269,7 @@ def run_loop(
         or checkpoint from here on always finds the decisions durably
         recorded), confirm optimistically, stage the POSTs."""
         nonlocal inflight, to_post, to_rebal
-        nonlocal to_post_seqs, to_rebal_seqs
+        nonlocal to_post_seqs, to_rebal_seqs, to_post_round
         result = bridge.finish_round(inflight)
         inflight = None
         to_post_seqs = _bind_seqs(result.bindings)
@@ -1248,12 +1279,14 @@ def run_loop(
         # optimistic confirm: the next build sees the new placements
         # now; the POSTs follow in the overlap window and a failure
         # re-queues/restores
-        for uid, machine in result.bindings.items():
-            bridge.confirm_binding(uid, machine)
-        for uid, (_frm, to) in result.migrations.items():
-            bridge.confirm_migration(uid, to)
-        for uid in result.preemptions:
-            bridge.confirm_preemption(uid)
+        with span("confirm", round=result.stats.round_num):
+            for uid, machine in result.bindings.items():
+                bridge.confirm_binding(uid, machine)
+            for uid, (_frm, to) in result.migrations.items():
+                bridge.confirm_migration(uid, to)
+            for uid in result.preemptions:
+                bridge.confirm_preemption(uid)
+        to_post_round = result.stats.round_num
         to_post = dict(result.bindings)
         to_rebal = (dict(result.migrations), dict(result.preemptions))
         return result
@@ -1263,20 +1296,21 @@ def run_loop(
         the journal's terminal entries — their effects now live in the
         snapshot). The final (shutdown) checkpoint writes
         synchronously after draining the writer."""
-        snap = ckpt_mgr.capture(bridge, watcher)
-        bridge.trace.emit(
-            "CHECKPOINT", round_num=bridge.round_num,
-            detail={"cadence": args.checkpoint_every,
-                    "warm": snap.warm_seed is not None,
-                    "final": final},
-        )
-        bridge.trace.flush()
-        if journal is not None:
-            journal.rotate()
-        if final:
-            ckpt_mgr.close(final_snap=snap)
-        else:
-            ckpt_mgr.submit(snap)
+        with span("checkpoint", round=bridge.round_num):
+            snap = ckpt_mgr.capture(bridge, watcher)
+            bridge.trace.emit(
+                "CHECKPOINT", round_num=bridge.round_num,
+                detail={"cadence": args.checkpoint_every,
+                        "warm": snap.warm_seed is not None,
+                        "final": final},
+            )
+            bridge.trace.flush()
+            if journal is not None:
+                journal.rotate()
+            if final:
+                ckpt_mgr.close(final_snap=snap)
+            else:
+                ckpt_mgr.submit(snap)
 
     # overload watchdog state: consecutive round-deadline misses
     # (>= 2 declares degraded=overload; a met deadline clears it)
@@ -1325,36 +1359,37 @@ def run_loop(
         """Log + count one completed round; True = max_rounds reached
         (any not-yet-POSTed deltas are flushed before exiting)."""
         nonlocal rounds
-        _log_round(result)
-        _watchdog(result.stats)
-        if health is not None:
-            # /readyz flips once a round over real observed state
-            # landed — proven-empty counts (the latch updates the
-            # poseidon_ready gauge itself)
-            health.mark_round(result.stats.backend)
-        if sched_metrics is not None:
-            # live device memory next to the budget guard's
-            # prediction (CPU backends publish nothing); allocator
-            # bookkeeping, outside the round window by design
-            sched_metrics.record_live_hbm()
-        if slo_engine is not None:
-            # one SLO evaluation per completed round (the burn-rate
-            # windows are measured in rounds)
-            slo_engine.evaluate(result.stats.round_num)
-        rounds += 1
-        if round_hook is not None:
-            # deterministic injection seam (chaos harness, tests):
-            # runs on the driver thread between rounds
-            round_hook(rounds, result, bridge)
-        if ckpt_mgr is not None:
-            ckpt_mgr.record_age()
-            if rounds % max(args.checkpoint_every, 1) == 0:
-                _take_checkpoint()
-        if args.max_rounds and rounds >= args.max_rounds:
-            if flush:
-                _flush_pending()
-            return True
-        return False
+        with span("round_done", round=result.stats.round_num):
+            _log_round(result)
+            _watchdog(result.stats)
+            if health is not None:
+                # /readyz flips once a round over real observed state
+                # landed — proven-empty counts (the latch updates the
+                # poseidon_ready gauge itself)
+                health.mark_round(result.stats.backend)
+            if sched_metrics is not None:
+                # live device memory next to the budget guard's
+                # prediction (CPU backends publish nothing); allocator
+                # bookkeeping, outside the round window by design
+                sched_metrics.record_live_hbm()
+            if slo_engine is not None:
+                # one SLO evaluation per completed round (the burn-rate
+                # windows are measured in rounds)
+                slo_engine.evaluate(result.stats.round_num)
+            rounds += 1
+            if round_hook is not None:
+                # deterministic injection seam (chaos harness, tests):
+                # runs on the loop's thread between rounds
+                round_hook(rounds, result, bridge)
+            if ckpt_mgr is not None:
+                ckpt_mgr.record_age()
+                if rounds % max(args.checkpoint_every, 1) == 0:
+                    _take_checkpoint()
+            if args.max_rounds and rounds >= args.max_rounds:
+                if flush:
+                    _flush_pending()
+                return True
+            return False
 
     # bind only once construction can no longer raise: an exception
     # above would skip the finally below and leak the bound port +
@@ -1401,130 +1436,128 @@ def run_loop(
                 # never schedule against a lock someone else may hold
                 log.error("lease renewal failed; stepping down")
                 return 1
-            tick_start = time.perf_counter()
-            if outbox.pending:
-                # retry parked actuations (jittered backoff per
-                # entry; one probe failure aborts the pump — a down
-                # apiserver is not hammered once per entry). A settle
-                # proves the apiserver reachable again.
-                counts = outbox.pump()
-                if (counts["replayed"] or counts["already-applied"]
-                        or counts["stale"]):
-                    detector.note_success()
-            observed = _observe_tick()
-            if not observed and not detector.active:
-                time.sleep(args.polling_frequency / 1e6)
-                continue
-            # declared outage: keep rounding from last-known state
-            # (the round is usually empty — everything decided is
-            # confirmed — but readiness, SLO evaluation, and the
-            # time-to-recovery clock stay live, and recovery needs no
-            # warmup round)
-            if observed and health is not None:
-                # the seed LIST / first successful snapshot is applied
-                health.mark_seeded()
-            if not incremental and not pipelined:
-                bridge.warm_state = None
-            try:
-                if pipelined:
-                    # finish the solve dispatched last tick (its fetch
-                    # ran while we slept/polled/observed), then start
-                    # this tick's round and POST the finished round's
-                    # deltas while the new solve is in flight
-                    if inflight is not None:
-                        result = _finish_inflight()
-                        if _round_done(result, True):
-                            return 0
-                    if not incremental:
-                        # must happen AFTER finish_round (which commits
-                        # the fresh warm handle) and before the next
-                        # dispatch, or the flag silently does nothing
-                        bridge.warm_state = None
-                    ir = bridge.begin_round()
-                    if ir.result is not None:
-                        # empty round (nothing schedulable): completed
-                        # synchronously, nothing in flight
-                        if _round_done(ir.result, True):
-                            return 0
-                    else:
-                        inflight = ir
-                    _flush_pending()
-                else:
-                    correction_due = (
-                        not express
-                        or not bridge.solver.express_ready
-                        or ticks_since_round + 1
-                        >= max(args.express_correction_rounds, 1)
-                    )
-                    if not correction_due:
-                        # express context live and no correction due
-                        # this tick: the round is skipped, the express
-                        # window below keeps binding between ticks
-                        ticks_since_round += 1
-                    else:
-                        ticks_since_round = 0
-                        result = bridge.run_scheduler()
-                        # write-ahead: ALL of this round's intended
-                        # actuations hit the journal (one fsync)
-                        # before the first POST goes on the wire
-                        seqs = _bind_seqs(result.bindings)
-                        rebal_seqs = _rebal_seqs(
-                            result.migrations, result.preemptions
-                        )
-                        if result.bindings:
-                            for uid, machine, outcome in _post_bindings(
-                                client, bridge, result.bindings,
-                                journal=journal, seqs=seqs,
-                                outbox=outbox,
-                            ):
-                                _mark_bind(seqs, uid, outcome)
-                                if outcome in ("ok", "parked"):
-                                    # parked: confirm optimistically —
-                                    # the decision stands, the outbox
-                                    # owns the wire (a dead-letter
-                                    # revokes + re-queues later)
-                                    bridge.confirm_binding(uid, machine)
-                                else:
-                                    bridge.binding_failed(uid)
-                        if result.migrations or result.preemptions:
-                            _actuate_rebalance(
-                                client, bridge, result.migrations,
-                                result.preemptions, confirm=True,
-                                journal=journal, seqs=rebal_seqs,
-                                outbox=outbox,
-                            )
-                        if _round_done(result, False):
-                            return 0
-            except Exception:
-                # a failed round (oracle timeout, device fault) must not
-                # kill the daemon; state is rebuilt from the next poll
-                log.exception("scheduling round failed; skipping tick")
-                if inflight is not None:
-                    bridge.cancel_round(inflight)
-                    inflight = None
-                # deltas confirmed before the failure must still reach
-                # the apiserver — a persistently failing begin_round
-                # must not strand them Running-locally /
-                # Pending-remotely forever
+            with span("tick", round=bridge.round_num + 1):
+                tick_start = time.perf_counter()
+                if outbox.pending:
+                    # retry parked actuations (jittered backoff per
+                    # entry; one probe failure aborts the pump — a down
+                    # apiserver is not hammered once per entry). A settle
+                    # proves the apiserver reachable again.
+                    with span("outbox_pump", round=bridge.round_num):
+                        counts = outbox.pump()
+                    if (counts["replayed"] or counts["already-applied"]
+                            or counts["stale"]):
+                        detector.note_success()
+                observed = _observe_tick()
+                if not observed and not detector.active:
+                    with span("tick_sleep", round=bridge.round_num):
+                        time.sleep(args.polling_frequency / 1e6)
+                    continue
+                # declared outage: keep rounding from last-known state
+                # (the round is usually empty — everything decided is
+                # confirmed — but readiness, SLO evaluation, and the
+                # time-to-recovery clock stay live, and recovery needs no
+                # warmup round)
+                if observed and health is not None:
+                    # the seed LIST / first successful snapshot is applied
+                    health.mark_seeded()
+                if not incremental and not pipelined:
+                    bridge.warm_state = None
                 try:
-                    _flush_pending()
+                    if pipelined:
+                        # finish the solve dispatched last tick (its fetch
+                        # ran while we slept/polled/observed), then start
+                        # this tick's round and POST the finished round's
+                        # deltas while the new solve is in flight
+                        if inflight is not None:
+                            result = _finish_inflight()
+                            if _round_done(result, True):
+                                return 0
+                        if not incremental:
+                            # must happen AFTER finish_round (which commits
+                            # the fresh warm handle) and before the next
+                            # dispatch, or the flag silently does nothing
+                            bridge.warm_state = None
+                        with span("begin", round=bridge.round_num + 1):
+                            ir = bridge.begin_round()
+                        if ir.result is not None:
+                            # empty round (nothing schedulable): completed
+                            # synchronously, nothing in flight
+                            if _round_done(ir.result, True):
+                                return 0
+                        else:
+                            inflight = ir
+                        _flush_pending()
+                    else:
+                        correction_due = (
+                            not express
+                            or not bridge.solver.express_ready
+                            or ticks_since_round + 1
+                            >= max(args.express_correction_rounds, 1)
+                        )
+                        if not correction_due:
+                            # express context live and no correction due
+                            # this tick: the round is skipped, the express
+                            # window below keeps binding between ticks
+                            ticks_since_round += 1
+                        else:
+                            ticks_since_round = 0
+                            with span("begin", round=bridge.round_num + 1):
+                                ir = bridge.begin_round()
+                            result = bridge.finish_round(ir)
+                            rnd = result.stats.round_num
+                            # write-ahead: ALL of this round's intended
+                            # actuations hit the journal (one fsync)
+                            # before the first POST goes on the wire
+                            rebal_seqs = _rebal_seqs(
+                                result.migrations, result.preemptions
+                            )
+                            if result.bindings:
+                                _post_and_confirm(
+                                    result.bindings, rnd, "round"
+                                )
+                            if result.migrations or result.preemptions:
+                                _actuate_rebalance(
+                                    client, bridge, result.migrations,
+                                    result.preemptions, confirm=True,
+                                    journal=journal, seqs=rebal_seqs,
+                                    outbox=outbox, round_num=rnd,
+                                )
+                            if _round_done(result, False):
+                                return 0
                 except Exception:
-                    log.exception("deferred delta POSTs failed")
-                time.sleep(args.polling_frequency / 1e6)
-                continue
-            elapsed = time.perf_counter() - tick_start
-            remaining = max(args.polling_frequency / 1e6 - elapsed, 0.0)
-            if express and remaining > 0 and not overloaded:
-                # the inter-tick sleep IS the express window: block on
-                # the pods watch stream and bind arrivals immediately.
-                # Declared overload skips the window entirely — the
-                # tick path absorbs the backlog in one solve.
-                if streaming:
-                    _stream_window(remaining)
+                    # a failed round (oracle timeout, device fault) must not
+                    # kill the daemon; state is rebuilt from the next poll
+                    log.exception("scheduling round failed; skipping tick")
+                    if inflight is not None:
+                        bridge.cancel_round(inflight)
+                        inflight = None
+                    # deltas confirmed before the failure must still reach
+                    # the apiserver — a persistently failing begin_round
+                    # must not strand them Running-locally /
+                    # Pending-remotely forever
+                    try:
+                        _flush_pending()
+                    except Exception:
+                        log.exception("deferred delta POSTs failed")
+                    with span("tick_sleep", round=bridge.round_num):
+                        time.sleep(args.polling_frequency / 1e6)
+                    continue
+                elapsed = time.perf_counter() - tick_start
+                remaining = max(args.polling_frequency / 1e6 - elapsed, 0.0)
+                if express and remaining > 0 and not overloaded:
+                    # the inter-tick sleep IS the express window: block on
+                    # the pods watch stream and bind arrivals immediately.
+                    # Declared overload skips the window entirely — the
+                    # tick path absorbs the backlog in one solve.
+                    with span("express_window", round=bridge.round_num):
+                        if streaming:
+                            _stream_window(remaining)
+                        else:
+                            _express_window(remaining)
                 else:
-                    _express_window(remaining)
-            else:
-                time.sleep(remaining)
+                    with span("tick_sleep", round=bridge.round_num):
+                        time.sleep(remaining)
     finally:
         if watcher is not None:
             watcher.stop()

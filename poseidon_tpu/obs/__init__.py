@@ -1,4 +1,4 @@
-"""Observability: metrics, health endpoints, span profiling, reports.
+"""Observability: metrics, health endpoints, program spans, reports.
 
 The reference collects ``SchedulerStats`` per round and drops them
 (scheduler_bridge.cc:170-172); seven PRs in, this reproduction has five
@@ -17,11 +17,17 @@ surface, in three pieces:
 - ``server``: a daemon-thread HTTP server exposing ``/metrics``,
   ``/healthz`` (process liveness) and ``/readyz`` (ready = the seed
   LIST applied AND the first certified round completed), with
-  ``HealthState`` as the driver-fed readiness latch.
-- ``spans`` + ``report``: phase-span profiling (every round and
-  express batch emits a structured span tree into the trace stream as
-  a ``SPAN`` event; an exporter turns them into Chrome-trace/Perfetto
-  JSON), and the trace analysis backend behind
+  ``HealthState`` as the readiness latch the loop feeds, and
+  ``/debug/profile?seconds=N``, a ``jax.profiler`` capture under
+  ``--flight_dir``.
+- ``spans``: ``span(name, stats, field, **ids)``, the ``poseidon/``
+  profiler span around every host phase of the daemon (tick, list,
+  observe, begin/build/dispatch/upload, finish/fetch_wait/apply,
+  journal, confirm, round_done, post_bindings/bind_post, outbox_pump,
+  checkpoint, tick_sleep, express_window, audit), each adding its
+  duration to the ``SchedulerStats`` field it times; during a
+  profiler capture the spans share the device ops' clock.
+- ``report``: the trace analysis backend behind
   ``python -m poseidon_tpu.trace report`` — round-latency percentiles
   by lane/build-mode, express event-to-bind percentiles,
   degrade/resync/timeout tallies with reasons, placement-churn

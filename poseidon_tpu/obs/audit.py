@@ -66,9 +66,10 @@ import dataclasses
 import logging
 import queue
 import threading
-import time
 
 import numpy as np
+
+from poseidon_tpu.obs.spans import span
 
 log = logging.getLogger(__name__)
 
@@ -284,14 +285,14 @@ class ShadowAuditor:
             self._process(snap)
 
     def _process(self, snap: AuditSnapshot) -> AuditResult:
-        t0 = time.perf_counter()
-        try:
-            res = self._audit(snap)
-        except Exception as e:  # never crash the daemon for an audit
-            log.exception("shadow audit failed (round %d)",
-                          snap.round_num)
-            res = AuditResult(round_num=snap.round_num, error=str(e))
-        res.audit_ms = (time.perf_counter() - t0) * 1000
+        with span("audit", round=snap.round_num) as sp:
+            try:
+                res = self._audit(snap)
+            except Exception as e:  # never crash the daemon for an audit
+                log.exception("shadow audit failed (round %d)",
+                              snap.round_num)
+                res = AuditResult(round_num=snap.round_num, error=str(e))
+        res.audit_ms = sp.ms
         with self._lock:
             self.last = res
             if res.error:

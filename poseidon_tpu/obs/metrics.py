@@ -711,6 +711,7 @@ class SchedulerMetrics:
                 build_mode=build_mode_label(stats.build_mode),
             )
             for phase, dur in (
+                ("list", stats.list_ms),
                 ("observe", stats.observe_ms),
                 ("build", stats.build_ms),
                 ("price", stats.price_ms),
@@ -719,6 +720,8 @@ class SchedulerMetrics:
                 ("dispatch", stats.dispatch_ms),
                 ("fetch_wait", stats.fetch_wait_ms),
                 ("overlap", stats.overlap_ms),
+                ("post", stats.post_ms),
+                ("finish", stats.finish_ms),
             ):
                 self.round_phase.set(dur, phase=phase)
             self.round_cost.set(stats.cost)

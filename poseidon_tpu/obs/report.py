@@ -15,9 +15,7 @@ over ``trace.read_trace`` builds:
   operator sees WHY the dense lane fell back, not just that it did;
 - **placement churn**: SCHEDULE / MIGRATE / PREEMPT / EVICT /
   EXPRESS_PLACE totals and per-round rates, deferred-delta pressure,
-  and the bind-failure count;
-- **spans** (when ``--trace_profile`` was on): per-phase duration p50s
-  across rounds.
+  and the bind-failure count.
 
 Everything is computed from the JSONL alone — the report runs against
 a live daemon's trace file or a post-mortem copy equally.
@@ -82,7 +80,6 @@ def analyze_trace(path: str, *, tenant: str = "") -> dict:
     }
     churn = collections.Counter()
     slo_breaches = collections.Counter()
-    span_phases: dict[str, list[float]] = collections.defaultdict(list)
     rounds = 0
     nonempty_rounds = 0
     express = collections.Counter()
@@ -128,17 +125,6 @@ def analyze_trace(path: str, *, tenant: str = "") -> dict:
             churn[ev.event] += 1
             if isinstance(ev.detail, dict) and "e2b_ms" in ev.detail:
                 e2b.append(float(ev.detail["e2b_ms"]))
-        elif ev.event == "SPAN":
-            d = ev.detail or {}
-            lane = d.get("lane", "round")
-            # recurse: subspans nest (fetch-wait under solve-wait)
-            stack = list(d.get("children", ()))
-            while stack:
-                child = stack.pop()
-                span_phases[
-                    f"{lane}:{child.get('name')}"
-                ].append(float(child.get("dur_ms", 0.0)))
-                stack.extend(child.get("children", ()))
         if ev.event in _CHURN_EVENTS and ev.event != "EXPRESS_PLACE":
             churn[ev.event] += 1
     per_round = max(nonempty_rounds, 1)
@@ -172,9 +158,6 @@ def analyze_trace(path: str, *, tenant: str = "") -> dict:
             },
             "deltas_deferred": deferred,
             "bind_failures": bind_failures,
-        },
-        "span_phase_p50_ms": {
-            k: _pct(v, 50) for k, v in sorted(span_phases.items())
         },
     }
 
@@ -240,9 +223,4 @@ def render_report(data: dict) -> str:
                 f"{ch['per_round'][k]:>12}")
     add(f"deltas deferred: {ch['deltas_deferred']}  "
         f"bind failures: {ch['bind_failures']}")
-    if data["span_phase_p50_ms"]:
-        add("")
-        add("-- span phases (p50 ms; --trace_profile) --")
-        for k, v in data["span_phase_p50_ms"].items():
-            add(f"{k:<28}{v:>10}")
     return "\n".join(out)

@@ -39,6 +39,13 @@ the discipline). Endpoints:
   JSON — per objective: spec, healthy, short/long burn rates, breach
   count, current value. 404 when no ``--slo`` objectives were
   declared.
+- ``GET /debug/profile?seconds=N``: one ``jax.profiler`` capture of N
+  seconds (default 5, at most 300) into a new directory under
+  ``profile_dir`` (the daemon's ``--flight_dir``); the body is that
+  directory. The trace holds the daemon's ``poseidon/`` host spans
+  (obs/spans.py) and the device's ops on one clock: open it in
+  Perfetto or TensorBoard. 409 while another capture runs (this
+  endpoint's or anyone's in the process), 404 without a directory.
 
 ``HealthState`` is the driver-fed latch behind ``/readyz``; the cli
 marks it from the observe/round loop (cli.py), tests drive it
@@ -50,11 +57,17 @@ from __future__ import annotations
 import http.server
 import json
 import logging
+import os
 import threading
+import time
+import urllib.parse
 
 from poseidon_tpu.obs.metrics import MetricsRegistry
 
 log = logging.getLogger(__name__)
+
+PROFILE_DEFAULT_S = 5.0
+PROFILE_MAX_S = 300.0
 
 
 class HealthState:
@@ -171,6 +184,7 @@ class ObsServer:
         host: str = "0.0.0.0",
         build: dict | None = None,
         slo=None,
+        profile_dir: str = "",
     ):
         self.registry = registry
         self.health = health
@@ -182,6 +196,11 @@ class ObsServer:
         # the SLO engine behind /slo (obs/slo.py; None = 404):
         # status() serves handler threads under the engine's own lock
         self.slo = slo
+        # /debug/profile: where captures go ("" = endpoint off), and
+        # the one-capture-at-a-time latch (handler threads, under _lock)
+        self.profile_dir = profile_dir
+        self._lock = threading.Lock()
+        self._profiling = False
         self._httpd: http.server.ThreadingHTTPServer | None = None
         self._thread: threading.Thread | None = None
 
@@ -231,6 +250,12 @@ class ObsServer:
                         self.send_response(200)
                         self.send_header("Content-Type",
                                          "application/json")
+                elif route == "/debug/profile":
+                    code, body = srv.capture_profile(
+                        _seconds(self.path)
+                    )
+                    self.send_response(code)
+                    self.send_header("Content-Type", "text/plain")
                 elif route == "/readyz":
                     if health.ready:
                         # condition details: did this process warm-
@@ -278,6 +303,37 @@ class ObsServer:
         log.info("obs server listening on %s:%d", self.host, self.port)
         return self.port
 
+    def capture_profile(
+        self, seconds: float
+    ) -> tuple[int, bytes]:  # pta: background-thread
+        """One profiler capture of ``seconds`` (a handler thread blocks
+        for it); returns the HTTP status and body."""
+        if not self.profile_dir:
+            return 404, b"no profile directory configured\n"
+        with self._lock:
+            if self._profiling:
+                return 409, b"a profile capture is already running\n"
+            self._profiling = True
+        try:
+            import jax
+
+            path = os.path.join(
+                self.profile_dir, f"profile-{time.time_ns() // 1000000}"
+            )
+            try:
+                jax.profiler.start_trace(path)
+            except RuntimeError as e:  # another session in the process
+                return 409, f"profiler busy: {e}\n".encode()
+            try:
+                time.sleep(seconds)
+            finally:
+                jax.profiler.stop_trace()
+            log.info("profile captured: %s (%.1f s)", path, seconds)
+            return 200, (path + "\n").encode()
+        finally:
+            with self._lock:
+                self._profiling = False
+
     def stop(self) -> None:
         if self._httpd is not None:
             self._httpd.shutdown()
@@ -293,3 +349,15 @@ class ObsServer:
 
     def __exit__(self, *exc) -> None:
         self.stop()
+
+
+def _seconds(path: str) -> float:  # pta: background-thread
+    """``?seconds=N`` of a /debug/profile request, bounded."""
+    query = urllib.parse.parse_qs(urllib.parse.urlsplit(path).query)
+    try:
+        secs = float(query.get("seconds", [PROFILE_DEFAULT_S])[0])
+    except ValueError:
+        return PROFILE_DEFAULT_S
+    if not secs >= 0.0:  # negative or NaN
+        return PROFILE_DEFAULT_S
+    return min(secs, PROFILE_MAX_S)

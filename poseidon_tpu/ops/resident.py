@@ -74,6 +74,7 @@ from poseidon_tpu.models.costs import (
     COST_MODEL_SELECTORS,
     build_cost_inputs_host,
 )
+from poseidon_tpu.obs.spans import span
 from poseidon_tpu.ops.dense_auction import (
     I32,
     INF,
@@ -792,24 +793,30 @@ def _resident_chain(
     ``warm_start`` is False the warm_* arrays are ignored (pass
     zeros).
     """
-    cost = model_fn(inputs_dev)
-    dev, domain_ok, pc_s, ra_s = _redensify(
-        dt, cost, n_prefs=n_prefs, smax=smax
-    )
-    if warm_start:
-        asg, lvl, floor, gap, converged, rounds, phases, _ = _solve(
-            dev, warm_asg, warm_lvl, warm_floor, jnp.int32(1),
-            alpha=alpha, max_rounds=max_rounds, smax=smax,
-            analytic_init=False,
+    # named scopes: each stage's HLO carries its name in its op_name
+    # metadata (pricing / redensify / auction / finalize)
+    with jax.named_scope("pricing"):
+        cost = model_fn(inputs_dev)
+    with jax.named_scope("redensify"):
+        dev, domain_ok, pc_s, ra_s = _redensify(
+            dt, cost, n_prefs=n_prefs, smax=smax
         )
-    else:
-        asg0, lvl0, floor0, eps0 = cold_start(dev, alpha)
-        asg, lvl, floor, gap, converged, rounds, phases, _ = _solve(
-            dev, asg0, lvl0, floor0, eps0, alpha=alpha,
-            max_rounds=max_rounds, smax=smax, analytic_init=True,
-        )
-    ch, primal = _finalize(dev, dt, pc_s, ra_s, asg)
-    chosen, alt = _decision_stats(dev, asg)
+    with jax.named_scope("auction"):
+        if warm_start:
+            asg, lvl, floor, gap, converged, rounds, phases, _ = _solve(
+                dev, warm_asg, warm_lvl, warm_floor, jnp.int32(1),
+                alpha=alpha, max_rounds=max_rounds, smax=smax,
+                analytic_init=False,
+            )
+        else:
+            asg0, lvl0, floor0, eps0 = cold_start(dev, alpha)
+            asg, lvl, floor, gap, converged, rounds, phases, _ = _solve(
+                dev, asg0, lvl0, floor0, eps0, alpha=alpha,
+                max_rounds=max_rounds, smax=smax, analytic_init=True,
+            )
+    with jax.named_scope("finalize"):
+        ch, primal = _finalize(dev, dt, pc_s, ra_s, asg)
+        chosen, alt = _decision_stats(dev, asg)
     # flat tuple out (DenseState is not a registered pytree); the
     # caller reassembles the warm handle host-side. ``cost`` rides
     # along so oracle-fallback paths reuse the priced arc table
@@ -1678,27 +1685,28 @@ class ResidentSolver:
         zeros_t = jnp.zeros(Tp, I32)
         zeros_m = jnp.zeros(Mp, I32)
 
-        t0 = time.perf_counter()
         with no_implicit_transfers():
-            if self._mesh is not None:
-                # the parallel/ production lane: one batched upload
-                # laid out task-sharded / machine-replicated — the
-                # fused chain compiles as an SPMD program whose dense
-                # table is Tp/width rows per device, bit-identical to
-                # the plain layout
-                from poseidon_tpu.parallel.sharded import (
-                    resident_round_shardings,
-                )
+            with span("upload", timings, "upload_ms"):
+                if self._mesh is not None:
+                    # the parallel/ production lane: one batched upload
+                    # laid out task-sharded / machine-replicated — the
+                    # fused chain compiles as an SPMD program whose
+                    # dense table is Tp/width rows per device, bit-
+                    # identical to the plain layout
+                    from poseidon_tpu.parallel.sharded import (
+                        resident_round_shardings,
+                    )
 
-                in_spec, dt_spec = resident_round_shardings(
-                    self._mesh, dt_host
-                )
-                inputs_dev, dt = jax.device_put(
-                    (inputs_host, dt_host), (in_spec, dt_spec)
-                )
-            else:
-                inputs_dev, dt = jax.device_put((inputs_host, dt_host))
-            timings["upload_ms"] = (time.perf_counter() - t0) * 1000
+                    in_spec, dt_spec = resident_round_shardings(
+                        self._mesh, dt_host
+                    )
+                    inputs_dev, dt = jax.device_put(
+                        (inputs_host, dt_host), (in_spec, dt_spec)
+                    )
+                else:
+                    inputs_dev, dt = jax.device_put(
+                        (inputs_host, dt_host)
+                    )
 
             t_dispatch = time.perf_counter()
             with enable_x64(True):
@@ -1801,13 +1809,15 @@ class ResidentSolver:
         timings = inflight.timings
         topo = inflight.topo
         T = inflight.T
-        t0 = time.perf_counter()
         try:
-            (asg_np, ch_np, conv, rounds, phases, primal_np, dom_ok,
-             chosen_np, alt_np, lvl_np, floor_np), \
-                t_done = inflight.future.result(
-                    timeout_s=self._fetch_deadline_s()
-                )
+            # fetch_wait is the part of the sync the caller actually
+            # blocked on; the rest elapsed under overlapped host work
+            with span("fetch_wait", timings, "fetch_wait_ms"):
+                (asg_np, ch_np, conv, rounds, phases, primal_np, dom_ok,
+                 chosen_np, alt_np, lvl_np, floor_np), \
+                    t_done = inflight.future.result(
+                        timeout_s=self._fetch_deadline_s()
+                    )
         except FetchTimeout:
             # degrade LOUDLY instead of blocking the round forever:
             # count it, drop the warm handle (device health unknown),
@@ -1822,9 +1832,6 @@ class ResidentSolver:
                 self._fetch_deadline_s(),
             )
             raise
-        # fetch_wait is the part of the sync the caller actually blocked
-        # on; the rest elapsed under overlapped host work
-        timings["fetch_wait_ms"] = (time.perf_counter() - t0) * 1000
         timings["solve_ms"] = (t_done - inflight.t_dispatch) * 1000
         timings["fetch_ms"] = 0.0
         state = inflight.state

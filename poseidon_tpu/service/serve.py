@@ -96,7 +96,7 @@ def run_serve(args: argparse.Namespace) -> int:
         health = HealthState(ready_gauge=sched_metrics.ready)
         obs_server = ObsServer(
             sched_metrics.registry, health, port=args.metrics_port,
-            host=args.metrics_host,
+            host=args.metrics_host, profile_dir=args.flight_dir,
         )
     service = SchedulingService(
         oracle_timeout_s=args.max_solver_runtime / 1e6,

@@ -41,18 +41,16 @@ plus ROUND records carrying the per-phase timing/stat payload
 (``SchedulerStats`` as a dict — including the round-pipeline timers:
 ``build_mode`` delta/full/legacy, ``dispatch_ms``, ``fetch_wait_ms``,
 ``overlap_ms``, ``wall_ms``; ``total_ms`` is the host critical path,
-excluding the overlap window where the loop worked on other rounds)
-and SPAN records carrying a structured per-phase span tree for a round
-or express batch (``--trace_profile``; the tree schema lives in
-``poseidon_tpu/obs/spans.py``, the consumers are the Chrome-trace
-exporter and ``python -m poseidon_tpu.trace report``).
+excluding the overlap window where the loop worked on other rounds).
+Phase timelines are not trace events: the daemon's ``poseidon/`` spans
+(``poseidon_tpu/obs/spans.py``) land in a ``jax.profiler`` trace, on the
+device's clock.
 
 **Clock contract.** ``timestamp_us`` is WALL-clock microseconds
 (``time.time()`` by default): it exists to correlate events across
 hosts and with apiserver/audit logs, and it is NOT safe to difference —
 NTP steps/slews make wall-clock intervals lie. Every DURATION in the
-stream (the ROUND record's ``*_ms`` timers, SPAN ``dur_ms``/``off_ms``
-values, EXPRESS_PLACE ``e2b_ms``) is therefore measured by the
+stream (the ROUND record's ``*_ms`` timers, EXPRESS_PLACE ``e2b_ms``) is therefore measured by the
 producers on the monotonic clock family (``time.monotonic`` /
 ``time.perf_counter``) and shipped as an already-computed value.
 Consumers: read durations from the payloads, never from timestamp
@@ -68,8 +66,7 @@ Command line: ``python -m poseidon_tpu.trace report <file>`` renders
 the operator's one-pager (round-latency percentiles by lane/build
 mode, express event-to-bind percentiles, degrade/resync/timeout
 tallies with reasons, placement-churn summary; ``--json`` for the raw
-data model), and ``python -m poseidon_tpu.trace chrome <file>`` writes
-a Chrome-trace/Perfetto JSON of the SPAN events.
+data model).
 """
 
 from __future__ import annotations
@@ -108,8 +105,6 @@ EVENT_TYPES = frozenset({
                         # scanned as one device program with ONE fetch
                         # (detail.windows/placements/fetches/
                         # failed_window; ops/resident.py stream lane)
-    "SPAN",             # per-round/per-batch phase span tree
-                        # (--trace_profile; obs/spans.py schema)
     "FLIGHTREC_DUMP",   # the anomaly flight recorder wrote a dump
                         # (detail.reason names the trigger, detail.path
                         # the manifest; obs/flightrec.py)
@@ -315,13 +310,12 @@ def read_trace(path: str):
 
 
 # ---------------------------------------------------------------------------
-# the analysis CLI: python -m poseidon_tpu.trace report|chrome <file>
+# the analysis CLI: python -m poseidon_tpu.trace report <file>
 # ---------------------------------------------------------------------------
 
 
 def main(argv: list[str] | None = None) -> int:
     import argparse
-    import sys as _sys
 
     p = argparse.ArgumentParser(
         prog="python -m poseidon_tpu.trace",
@@ -341,29 +335,15 @@ def main(argv: list[str] | None = None) -> int:
                      help="report only one tenant's events (the "
                           "service lane stamps each session's tenant "
                           "id onto its trace events)")
-    chrome = sub.add_parser(
-        "chrome",
-        help="export SPAN events (--trace_profile) as Chrome-trace/"
-             "Perfetto JSON for chrome://tracing / ui.perfetto.dev",
-    )
-    chrome.add_argument("file")
-    chrome.add_argument("-o", "--out", default="",
-                        help="output path (default: <file>.chrome.json)")
     args = p.parse_args(argv)
-    # local imports: obs.report/spans import back into this module
+    # local import: obs.report imports back into this module
     from poseidon_tpu.obs import report as _report
-    from poseidon_tpu.obs import spans as _spans
 
-    if args.cmd == "report":
-        data = _report.analyze_trace(args.file, tenant=args.tenant)
-        if args.json:
-            print(json.dumps(data, indent=2))
-        else:
-            print(_report.render_report(data))
+    data = _report.analyze_trace(args.file, tenant=args.tenant)
+    if args.json:
+        print(json.dumps(data, indent=2))
     else:
-        out = args.out or (args.file + ".chrome.json")
-        _spans.write_chrome_trace(read_trace(args.file), out)
-        print(out, file=_sys.stdout)
+        print(_report.render_report(data))
     return 0
 
 

@@ -158,11 +158,21 @@ class TestVocabularyGuard:
         assert len(gen.events) == 0
 
     def test_span_is_declared(self):
-        assert "SPAN" in EVENT_TYPES
+        """The span-fed phase timers travel in the declared ROUND
+        record (the profiler trace holds the spans themselves)."""
+        import dataclasses
+
+        from poseidon_tpu.bridge import SchedulerStats
+
+        assert "ROUND" in EVENT_TYPES
         gen = TraceGenerator()
-        gen.emit("SPAN", round_num=1,
-                 detail={"name": "round", "children": []})
-        assert gen.events[-1].event == "SPAN"
+        gen.emit("ROUND", round_num=1,
+                 detail=dataclasses.asdict(SchedulerStats(round_num=1)))
+        detail = gen.events[-1].detail
+        for field in ("list_ms", "observe_ms", "build_ms", "dispatch_ms",
+                      "upload_ms", "fetch_wait_ms", "finish_ms",
+                      "post_ms", "posts", "placed_wait_rounds"):
+            assert field in detail
 
     def test_bridge_emits_migrate_and_preempt_events(self):
         """The rebalancing round's decisions land in the trace

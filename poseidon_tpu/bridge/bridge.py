@@ -40,7 +40,7 @@ Round pipeline: the round is split into
 the placement download starts immediately on a background thread) and
 ``finish_round`` (join the fetch, apply placement deltas). Serial
 callers use ``run_scheduler`` — exactly ``finish_round(begin_round())``
-— while pipelined drivers (cli.py, bench.py config 4) do next-round
+— while a pipelined driver (bench.py config 4) does next-round
 host work (poll parse, observe, KnowledgeBase feed, binding POSTs of
 the previous round) between the two calls, so the device solve and
 its download elapse under host work instead of serializing after it.
@@ -159,9 +159,9 @@ class SchedulerStats:
     previous round began, moved into stats at begin like
     ``observe_ms``; ``post_ms``/``posts`` are the actuation POSTs the
     daemon loop issued (bindings, evictions, migrations: their wall time
-    and their count) since the previous round's stats were emitted — in the
-    pipelined loop the previous round's POSTs, made in this round's
-    overlap window — moved into stats when this round's are emitted.
+    and their count) since the previous round's stats were emitted — in
+    the daemon loop the previous round's own POSTs, made after its join
+    — moved into stats when this round's are emitted.
     ``finish_ms`` is ``finish_round``'s apply phase (delta extraction
     and the per-pod events, logs and lifecycle stamps, after the join).
     ``placed_wait_rounds`` counts, over the pods this round placed, the
@@ -2165,7 +2165,7 @@ class SchedulerBridge:
 
     def revoke_binding(self, uid: str) -> None:
         """A bindings POST failed after an optimistic ``confirm_binding``
-        (the pipelined loop confirms before POSTing, cli.py): flip the
+        (a caller that confirms before POSTing): flip the
         pod back to Pending so the next round re-offers it. The pod
         re-enters the pending order mid-sequence, so the next graph
         build is a full rebuild."""
@@ -2249,9 +2249,9 @@ class SchedulerBridge:
         """A bindings POST for a PLACE failed: count it and re-queue the
         pod as unscheduled — aging preserved and bumped like any other
         round it sat waiting — instead of silently believing the
-        placement landed. Handles both the serial path (pod still
-        Pending, never confirmed) and the optimistic pipelined path
-        (pod confirmed Running first: revoked, then aged)."""
+        placement landed. Handles both a caller that confirms after
+        the POST (pod still Pending, never confirmed) and one that
+        confirmed first (pod Running: revoked, then aged)."""
         self._bind_failures += 1
         if self.express_lane:
             # whether revoked or never confirmed, the pod's on-HBM row

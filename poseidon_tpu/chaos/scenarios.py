@@ -236,9 +236,6 @@ def scenario_apiserver_outage(
         ),
         rounds=rounds, fault_clear_round=begin + duration,
         recover_within=rounds - begin - duration - 1,
-        # pipelined: POSTs ride the overlap window, so the outage
-        # window catches the staged POSTs exactly as decided
-        flags=("--round_pipeline=true",),
     )
 
 
@@ -277,8 +274,8 @@ def scenario_composite(
     return ChaosScenario(
         name="composite", seed=seed, nodes=nodes, pods=pods,
         actions=(
-            # burst decided at round ~9; its POSTs ride the next
-            # tick's overlap window — exactly when the outage begins
+            # burst decided at round ~9; its POSTs follow that
+            # round's hook — exactly when the outage begins
             FaultAction(8, "pod_burst", {"n": 24}),
             FaultAction(9, "outage_begin"),
             FaultAction(16, "outage_end"),

@@ -18,20 +18,15 @@ Differences from the reference loop, on purpose:
   that arrived during a failed tick);
 - successful bindings are confirmed into the bridge immediately so the
   next round's capacity math does not depend on poll latency;
-- the round is pipelined by default (``--round_pipeline=true``, the
-  SURVEY §7 suggestion the reference never implemented): the solve for
-  round N is dispatched asynchronously and its placement download runs
-  on a background thread, while the loop POSTs round N-1's bindings,
-  sleeps, and parses/observes the next poll — so the device solve and
-  its download elapse under host work instead of after it. A solve never
-  runs against stale observations: each round is built AFTER that
-  tick's poll is applied and AFTER the previous round's placements
-  landed; only *unrelated* work overlaps the in-flight solve.
-  ``--round_pipeline=false`` restores the strictly serial tick.
-  Pipelined binding POSTs are confirmed optimistically (the bridge
-  marks the pod Running when the round finishes, the POST follows in
-  the next tick's overlap window); a failed POST revokes the binding
-  so the pod is re-offered;
+- each round is joined in the tick that dispatched it: the solve is
+  dispatched asynchronously (its placement download runs on the
+  solver's background thread), joined at once, and the round's
+  bindings are POSTed before the next poll. The SURVEY §7 pipeline,
+  which carried the solve into the next tick under its poll and
+  observe, held every finished binding on the host for a whole tick
+  while the solve it hid took milliseconds; ``--round_pipeline`` is
+  still accepted, so existing flagfiles parse, and both its values run
+  this tick;
 - ``--watch=true`` replaces the full-list poll with the Kubernetes
   watch protocol (apiclient/watch.py): one seeding LIST, then typed
   ADDED/MODIFIED/DELETED events streamed from a ``resourceVersion``
@@ -42,7 +37,7 @@ Differences from the reference loop, on purpose:
   eviction guard intact) on 410 Gone, decode errors, or
   ``--watch_max_lag`` seconds without stream activity; resyncs and
   reconnects are trace events and ``SchedulerStats`` counters. Watch
-  composes with ``--round_pipeline`` and ``--enable_preemption``.
+  composes with ``--enable_preemption``.
 
 - ``--express_lane=true`` (with ``--watch=true``) adds the between-
   ticks fast path: the inter-tick sleep becomes an express window that
@@ -54,9 +49,7 @@ Differences from the reference loop, on purpose:
   (``--express_correction_rounds``) that differential-verifies express
   placements; anything the express vocabulary cannot represent (node
   events, stream degradation, oversize batches) degrades loudly to the
-  round path. Serial ticks (``--round_pipeline`` is ignored: the
-  pipeline would park a solve in flight across the very window the
-  express lane lives in).
+  round path.
 
 Run: ``python -m poseidon_tpu.cli --k8s_apiserver_port=8080
 --flow_scheduling_cost_model=quincy --max_rounds=0``
@@ -105,9 +98,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="reuse on-HBM warm state across rounds")
     p.add_argument("--round_pipeline",
                    default="true", choices=["true", "false"],
-                   help="overlap the in-flight solve/fetch with next-"
-                        "round host work (poll, observe, binding "
-                        "POSTs); false = strictly serial ticks")
+                   help="ignored, kept so existing flagfiles parse: "
+                        "every round is joined, and its bindings "
+                        "POSTed, in the tick that dispatched it")
     p.add_argument("--incremental_build",
                    default="true", choices=["true", "false"],
                    help="O(churn) delta graph builds across rounds; "
@@ -171,9 +164,8 @@ def build_parser() -> argparse.ArgumentParser:
     # the last round's warm dense state (ops/resident.py express
     # kernels); the full resident round is demoted to a periodic
     # correction pass that differential-verifies express placements.
-    # Requires --watch (events are the trigger) and runs serial ticks
-    # (the inter-tick window IS the express window, so --round_pipeline
-    # — which parks a solve in flight across that window — is ignored)
+    # Requires --watch (events are the trigger; the inter-tick window
+    # IS the express window)
     p.add_argument("--express_lane",
                    default="false", choices=["true", "false"],
                    help="bind small pod-arrival batches between round "
@@ -229,7 +221,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--max_solver_runtime", type=int,
                    default=1_000_000_000,
                    help="microseconds; bounds one oracle-fallback solve "
-                        "AND the pipelined round's background placement "
+                        "AND the round's background placement "
                         "fetch (a miss degrades loudly: FETCH_TIMEOUT "
                         "trace event + stats counter, round abandoned; "
                         "the TPU kernel itself is bounded by its round "
@@ -521,7 +513,7 @@ def _actuate_rebalance(client, bridge, migrations, preemptions, *,
     """Actuate MIGRATE (evict + re-bind) and PREEMPT (evict) deltas.
 
     ``confirm=True`` is the serial contract (state changes only after
-    the POSTs land); ``confirm=False`` the optimistic pipelined one
+    the POSTs land); ``confirm=False`` the daemon loop's optimistic one
     (the bridge already confirmed at finish time — failures restore the
     pod to its old machine and the next poll reconciles). Journaled
     like the bindings: intents must already be on disk; this marks
@@ -594,9 +586,9 @@ def run_loop(
 
     ``stop_event`` is the graceful-shutdown latch: SIGTERM sets it (a
     handler is installed when running on the main thread; embedded
-    drivers and tests pass their own event) and the loop then finishes
-    the in-flight round, flushes its deltas, writes a final checkpoint
-    + trace flush, and exits 0. ``lease`` (ha/standby.LeaderElector)
+    drivers and tests pass their own event) and the loop then stops
+    between ticks (each round was POSTed in its own tick), drains the
+    outbox once, writes a final checkpoint + trace flush, and exits 0. ``lease`` (ha/standby.LeaderElector)
     is renewed every tick in HA mode — a failed renewal steps down
     with exit code 1 instead of scheduling against a lost lock.
     ``preloaded`` short-circuits the checkpoint read with a snapshot a
@@ -799,7 +791,6 @@ def run_loop(
             if obs_server is not None:
                 obs_server.slo = slo_engine
     incremental = args.run_incremental_scheduler == "true"
-    pipelined = args.round_pipeline == "true"
     stats_fh = open(args.stats_json, "a") if args.stats_json else None
     watcher = None
     if args.watch == "true":
@@ -818,16 +809,6 @@ def run_loop(
             "express trigger); express lane disabled"
         )
         express = False
-    if express and pipelined:
-        # the pipeline parks a solve in flight across the inter-tick
-        # window — exactly where the express lane lives; serial
-        # correction rounds replace it (the express dispatch is the
-        # new latency hider)
-        log.info(
-            "--express_lane runs serial correction rounds; "
-            "--round_pipeline ignored"
-        )
-        pipelined = False
     if express and not incremental:
         log.warning(
             "--express_lane needs warm on-HBM state "
@@ -850,8 +831,6 @@ def run_loop(
     ) if express else (
         "watch" if watcher is not None else "poll"
     )
-    if pipelined:
-        lane += "+pipelined"
     if args.mesh_width:
         lane += "+sharded"
     if args.aggregate_classes == "true":
@@ -920,8 +899,8 @@ def run_loop(
                 snap.warm_seed is not None,
             )
 
-    # graceful shutdown: SIGTERM finishes the in-flight round, flushes
-    # deltas + trace + a final checkpoint, and exits 0
+    # graceful shutdown: SIGTERM stops the loop between ticks, flushes
+    # trace + a final checkpoint, and exits 0
     stop = stop_event if stop_event is not None else threading.Event()
     try:
         signal.signal(signal.SIGTERM, lambda _s, _f: stop.set())
@@ -1050,36 +1029,31 @@ def run_loop(
             (journal.confirmed if outcome == "ok"
              else journal.failed)(seq)
 
-    def _post_and_confirm(bindings, round_num, lane) -> None:
-        """Journal, then POST bindings not yet confirmed (serial rounds,
-        express batches): a landed POST confirms the pod, and so does a
-        parked one (the decision stands, the outbox owns the wire; a
-        dead-letter revokes and re-queues later); a rejected one
-        re-queues it."""
+    def _post_express(result) -> None:
+        """Journal, then POST one express batch's bindings: a landed
+        POST confirms the pod, and so does a parked one (the decision
+        stands, the outbox owns the wire; a dead-letter revokes and
+        re-queues later); a rejected one re-queues it (the bridge
+        invalidates the context, so the next full round owns
+        recovery)."""
+        if result is None or not result.bindings:
+            return
+        bindings, rnd = result.bindings, bridge.round_num
         seqs = _bind_seqs(bindings)
-        with _post_span(bridge, len(bindings), round_num):
+        with _post_span(bridge, len(bindings), rnd):
             for uid, machine, outcome in _post_bindings(
                 client, bridge, bindings, journal=journal,
-                seqs=seqs, outbox=outbox, round_num=round_num,
+                seqs=seqs, outbox=outbox, round_num=rnd,
             ):
                 _mark_bind(seqs, uid, outcome)
                 if outcome in ("ok", "parked"):
                     bridge.confirm_binding(uid, machine)
                 else:
                     log.warning(
-                        "%s bind POST failed for %s; re-queueing",
-                        lane, uid,
+                        "express bind POST failed for %s; re-queueing",
+                        uid,
                     )
                     bridge.binding_failed(uid)
-
-    def _post_express(result) -> None:
-        """POST one express batch's bindings; rejections re-queue (the
-        bridge invalidates the context, so the next full round owns
-        recovery); unreachable POSTs park in the outbox with the pod
-        confirmed."""
-        if result is None or not result.bindings:
-            return
-        _post_and_confirm(result.bindings, bridge.round_num, "express")
 
     def _express_window(window_s: float) -> None:
         """The inter-tick express window: turn small watch-event
@@ -1199,17 +1173,6 @@ def run_loop(
                     bridge.stream_flush()
 
     rounds = 0
-    # round-pipeline state: at most one solve in flight across ticks,
-    # plus the finished-but-not-yet-POSTed deltas of the last round
-    # (and their journal intent seqs — written at finish time, before
-    # the optimistic confirms, so a checkpoint taken between rounds is
-    # always consistent with the journal)
-    inflight = None
-    to_post: dict[str, str] = {}
-    to_rebal: tuple[dict, dict] = ({}, {})
-    to_post_seqs: dict = {}
-    to_rebal_seqs: dict = {}
-    to_post_round = 0      # the round that decided the staged deltas
     # express-lane demotion state: full rounds become a periodic
     # correction pass (every --express_correction_rounds ticks) while
     # the express context is live; a dead context forces the round
@@ -1230,55 +1193,13 @@ def run_loop(
             stats_fh.write(json.dumps(vars(s)) + "\n")
             stats_fh.flush()
 
-    def _post_and_revoke(to_post, seqs, round_num):
-        """POST optimistically-confirmed bindings; rejections re-queue
-        the pod as unscheduled (counted in SchedulerStats) so it is
-        re-offered next round; unreachable POSTs park in the outbox
-        (the pod stays confirmed — outage semantics)."""
-        with _post_span(bridge, len(to_post), round_num):
-            for uid, machine, outcome in _post_bindings(
-                client, bridge, to_post, journal=journal, seqs=seqs,
-                outbox=outbox, round_num=round_num,
-            ):
-                _mark_bind(seqs, uid, outcome)
-                if outcome not in ("ok", "parked"):
-                    log.warning(
-                        "bind POST failed for %s; re-queueing", uid
-                    )
-                    bridge.binding_failed(uid)
-
-    def _flush_pending():
-        """POST any deltas still queued from the last finished round."""
-        nonlocal to_post, to_rebal, to_post_seqs, to_rebal_seqs
-        if to_post:
-            _post_and_revoke(to_post, to_post_seqs, to_post_round)
-            to_post = {}
-            to_post_seqs = {}
-        if to_rebal[0] or to_rebal[1]:
-            _actuate_rebalance(
-                client, bridge, to_rebal[0], to_rebal[1],
-                confirm=False, journal=journal, seqs=to_rebal_seqs,
-                outbox=outbox, round_num=to_post_round,
-            )
-            to_rebal = ({}, {})
-            to_rebal_seqs = {}
-
-    def _finish_inflight():
-        """Join the in-flight solve: journal its deltas' intents (the
-        write-ahead edge — BEFORE the optimistic confirms, so a crash
-        or checkpoint from here on always finds the decisions durably
-        recorded), confirm optimistically, stage the POSTs."""
-        nonlocal inflight, to_post, to_rebal
-        nonlocal to_post_seqs, to_rebal_seqs, to_post_round
-        result = bridge.finish_round(inflight)
-        inflight = None
-        to_post_seqs = _bind_seqs(result.bindings)
-        to_rebal_seqs = _rebal_seqs(
-            result.migrations, result.preemptions
-        )
-        # optimistic confirm: the next build sees the new placements
-        # now; the POSTs follow in the overlap window and a failure
-        # re-queues/restores
+    def _confirm_round(result):
+        """Journal the round's deltas (the write-ahead edge: intents are
+        on disk BEFORE the optimistic confirms, so a crash or checkpoint
+        from here on finds the decisions durably recorded), then confirm
+        them in the bridge. Returns the journal seqs for ``_actuate``."""
+        seqs = _bind_seqs(result.bindings)
+        rebal_seqs = _rebal_seqs(result.migrations, result.preemptions)
         with span("confirm", round=result.stats.round_num):
             for uid, machine in result.bindings.items():
                 bridge.confirm_binding(uid, machine)
@@ -1286,10 +1207,33 @@ def run_loop(
                 bridge.confirm_migration(uid, to)
             for uid in result.preemptions:
                 bridge.confirm_preemption(uid)
-        to_post_round = result.stats.round_num
-        to_post = dict(result.bindings)
-        to_rebal = (dict(result.migrations), dict(result.preemptions))
-        return result
+        return seqs, rebal_seqs
+
+    def _actuate(result, seqs, rebal_seqs) -> None:
+        """POST a confirmed round's deltas: a rejected binding re-queues
+        its pod as unscheduled (counted in SchedulerStats) so it is
+        re-offered next round, a failed migration or eviction restores
+        the pod; unreachable POSTs park in the outbox (the pod stays
+        confirmed: outage semantics)."""
+        rnd = result.stats.round_num
+        if result.bindings:
+            with _post_span(bridge, len(result.bindings), rnd):
+                for uid, machine, outcome in _post_bindings(
+                    client, bridge, result.bindings, journal=journal,
+                    seqs=seqs, outbox=outbox, round_num=rnd,
+                ):
+                    _mark_bind(seqs, uid, outcome)
+                    if outcome not in ("ok", "parked"):
+                        log.warning(
+                            "bind POST failed for %s; re-queueing", uid
+                        )
+                        bridge.binding_failed(uid)
+        if result.migrations or result.preemptions:
+            _actuate_rebalance(
+                client, bridge, result.migrations, result.preemptions,
+                confirm=False, journal=journal, seqs=rebal_seqs,
+                outbox=outbox, round_num=rnd,
+            )
 
     def _take_checkpoint(final: bool = False):
         """Capture + hand off one warm-state checkpoint (and rotate
@@ -1355,9 +1299,8 @@ def run_loop(
                 if sched_metrics is not None:
                     sched_metrics.record_overload_cleared()
 
-    def _round_done(result, flush):
-        """Log + count one completed round; True = max_rounds reached
-        (any not-yet-POSTed deltas are flushed before exiting)."""
+    def _round_done(result):
+        """Log + count one completed round; True = max_rounds reached."""
         nonlocal rounds
         with span("round_done", round=result.stats.round_num):
             _log_round(result)
@@ -1385,11 +1328,7 @@ def run_loop(
                 ckpt_mgr.record_age()
                 if rounds % max(args.checkpoint_every, 1) == 0:
                     _take_checkpoint()
-            if args.max_rounds and rounds >= args.max_rounds:
-                if flush:
-                    _flush_pending()
-                return True
-            return False
+            return bool(args.max_rounds and rounds >= args.max_rounds)
 
     # bind only once construction can no longer raise: an exception
     # above would skip the finally below and leak the bound port +
@@ -1399,22 +1338,10 @@ def run_loop(
     try:
         while True:
             if stop.is_set():
-                # graceful shutdown: finish what is in flight, flush
-                # the staged deltas, and let the finally block write
-                # the final checkpoint + trace flush
-                log.info(
-                    "shutdown requested; finishing in-flight round"
-                )
-                if inflight is not None:
-                    try:
-                        _log_round(_finish_inflight())
-                    except Exception:
-                        log.exception(
-                            "in-flight round failed during shutdown"
-                        )
-                        bridge.cancel_round(inflight)
-                        inflight = None
-                _flush_pending()
+                # graceful shutdown: every round was joined and POSTed
+                # in its own tick; drain the outbox and let the finally
+                # block write the final checkpoint + trace flush
+                log.info("shutdown requested")
                 if outbox.pending:
                     # one immediate best-effort drain (backoff
                     # ignored: the process is leaving). Whatever
@@ -1461,85 +1388,49 @@ def run_loop(
                 if observed and health is not None:
                     # the seed LIST / first successful snapshot is applied
                     health.mark_seeded()
-                if not incremental and not pipelined:
+                if not incremental:
                     bridge.warm_state = None
+                ir = None
                 try:
-                    if pipelined:
-                        # finish the solve dispatched last tick (its fetch
-                        # ran while we slept/polled/observed), then start
-                        # this tick's round and POST the finished round's
-                        # deltas while the new solve is in flight
-                        if inflight is not None:
-                            result = _finish_inflight()
-                            if _round_done(result, True):
-                                return 0
-                        if not incremental:
-                            # must happen AFTER finish_round (which commits
-                            # the fresh warm handle) and before the next
-                            # dispatch, or the flag silently does nothing
-                            bridge.warm_state = None
+                    correction_due = (
+                        not express
+                        or not bridge.solver.express_ready
+                        or ticks_since_round + 1
+                        >= max(args.express_correction_rounds, 1)
+                    )
+                    if not correction_due:
+                        # express context live and no correction due
+                        # this tick: the round is skipped, the express
+                        # window below keeps binding between ticks
+                        ticks_since_round += 1
+                    else:
+                        ticks_since_round = 0
+                        # the solve is dispatched, then joined at once;
+                        # the round is confirmed, counted (the round hook
+                        # runs between decision and actuation) and its
+                        # deltas go on the wire before the next poll
                         with span("begin", round=bridge.round_num + 1):
                             ir = bridge.begin_round()
-                        if ir.result is not None:
-                            # empty round (nothing schedulable): completed
-                            # synchronously, nothing in flight
-                            if _round_done(ir.result, True):
-                                return 0
-                        else:
-                            inflight = ir
-                        _flush_pending()
-                    else:
-                        correction_due = (
-                            not express
-                            or not bridge.solver.express_ready
-                            or ticks_since_round + 1
-                            >= max(args.express_correction_rounds, 1)
-                        )
-                        if not correction_due:
-                            # express context live and no correction due
-                            # this tick: the round is skipped, the express
-                            # window below keeps binding between ticks
-                            ticks_since_round += 1
-                        else:
-                            ticks_since_round = 0
-                            with span("begin", round=bridge.round_num + 1):
-                                ir = bridge.begin_round()
-                            result = bridge.finish_round(ir)
-                            rnd = result.stats.round_num
-                            # write-ahead: ALL of this round's intended
-                            # actuations hit the journal (one fsync)
-                            # before the first POST goes on the wire
-                            rebal_seqs = _rebal_seqs(
-                                result.migrations, result.preemptions
-                            )
-                            if result.bindings:
-                                _post_and_confirm(
-                                    result.bindings, rnd, "round"
-                                )
-                            if result.migrations or result.preemptions:
-                                _actuate_rebalance(
-                                    client, bridge, result.migrations,
-                                    result.preemptions, confirm=True,
-                                    journal=journal, seqs=rebal_seqs,
-                                    outbox=outbox, round_num=rnd,
-                                )
-                            if _round_done(result, False):
-                                return 0
+                        result = bridge.finish_round(ir)
+                        ir = None
+                        seqs = _confirm_round(result)
+                        try:
+                            done = _round_done(result)
+                        finally:
+                            # confirmed deltas reach the apiserver even
+                            # when the round's bookkeeping raised
+                            _actuate(result, *seqs)
+                        if done:
+                            return 0
                 except Exception:
                     # a failed round (oracle timeout, device fault) must not
                     # kill the daemon; state is rebuilt from the next poll
                     log.exception("scheduling round failed; skipping tick")
-                    if inflight is not None:
-                        bridge.cancel_round(inflight)
-                        inflight = None
-                    # deltas confirmed before the failure must still reach
-                    # the apiserver — a persistently failing begin_round
-                    # must not strand them Running-locally /
-                    # Pending-remotely forever
-                    try:
-                        _flush_pending()
-                    except Exception:
-                        log.exception("deferred delta POSTs failed")
+                    if ir is not None:
+                        # the join failed (a fetch past its deadline):
+                        # drain and drop the solve so the next round
+                        # starts clean
+                        bridge.cancel_round(ir)
                     with span("tick_sleep", round=bridge.round_num):
                         time.sleep(args.polling_frequency / 1e6)
                     continue

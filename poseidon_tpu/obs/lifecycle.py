@@ -113,8 +113,8 @@ class LifecycleTracker:
         # stage stamps (decided/journal/posted offsets, debugging)
         self.last_closed: tuple[str, str, float] | None = None
         self.last_closed_stages: dict = {}
-        # recently-closed stamps, bounded: the pipelined driver
-        # confirms OPTIMISTICALLY (before the POST), so a failed POST
+        # recently-closed stamps, bounded: when a driver confirms
+        # OPTIMISTICALLY (before the POST), a failed POST
         # must be able to REOPEN the timeline from its original event
         # stamp — otherwise the pod's real (longer) wait is never
         # measured and the histogram reads optimistic exactly when
@@ -193,8 +193,8 @@ class LifecycleTracker:
         its event-to-confirmed sample (ms, monotonic). Returns the
         sample, or None for an untracked uid.
 
-        The pipelined driver confirms OPTIMISTICALLY (POST follows in
-        the overlap window), so this sample measures event-to-commit;
+        A driver that confirms OPTIMISTICALLY (the POST follows) makes
+        this sample measure event-to-commit;
         if the POST then fails, ``reopen`` restores the timeline from
         its original event stamp and the eventual successful bind
         records the pod's full wait as a second sample."""

@@ -633,4 +633,4 @@ class TestDaemonE2E:
         lanes = {e.detail.get("lane") for e in events
                  if e.event == "ROUND" and e.detail
                  and e.detail.get("backend")}
-        assert "watch+pipelined" in lanes
+        assert "watch" in lanes

@@ -71,11 +71,13 @@ def test_span_adds_its_duration_to_the_field_and_nests():
 
 @pytest.fixture(scope="module")
 def profiled_daemon(tmp_path_factory):
-    """Four pipelined poll rounds of ``run_loop`` against the fake
-    apiserver inside one profiler session (CPU backend). The cluster
-    holds 12 seats for 14 pods; after each round the hook completes
-    two running pods and submits two new ones, so some pods wait a
-    round and every round after the first has POSTs in its window."""
+    """Four poll rounds of ``run_loop`` against the fake apiserver
+    inside one profiler session (CPU backend). The cluster holds 12
+    seats for 14 pods; after each round the hook completes two running
+    pods and submits two new ones, so some pods wait a round. Every
+    round after the first carries in its stats the POSTs made since the
+    previous round's stats: that round's bindings, POSTed after its
+    join in its own tick."""
     from poseidon_tpu.apiclient import FakeApiServer
     from poseidon_tpu.cli import parse_args, run_loop
 

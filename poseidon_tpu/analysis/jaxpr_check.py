@@ -246,7 +246,7 @@ def trace_production_kernels() -> dict[str, object]:
     model_fn = ctx.model_fn
     kmax = solver.express_max_batch
     pk = ctx.n_prefs
-    Tp, Mp = ctx.dev.c.shape
+    Tp = ctx.dev.c.shape[0]
 
     arrival = res.ExpressArrival(
         uid="jaxpr-audit-pod", wait_rounds=0, cpu_milli=100,
@@ -290,7 +290,6 @@ def trace_production_kernels() -> dict[str, object]:
     stacked = stack_members(members, 2)
     bsmax = max(min(int(np.max(members[0]["slots"], initial=0)), bTp), 1)
 
-    zeros_t = np.zeros(Tp, np.int32)
     zeros_bt = np.zeros(bTp, np.int32)
     zeros_bm = np.zeros(bMp, np.int32)
     patch_w = res._EXPRESS_PATCH_CHUNK
@@ -304,10 +303,10 @@ def trace_production_kernels() -> dict[str, object]:
                 )
             )(ctx.dev, warm.asg, warm.lvl, warm.floor, np.int32(1)),
             "resident_chain": jax.make_jaxpr(
-                lambda dt, inp, a, l, f: res._resident_chain(
-                    dt, inp, a, l, f, model_fn=model_fn,
+                lambda dt, inp: res._resident_chain(
+                    dt, inp, model_fn=model_fn,
                     n_prefs=pk, smax=ctx.smax, alpha=solver.alpha,
-                    max_rounds=64, warm_start=False,
+                    max_rounds=64,
                 )
             )(
                 ctx.dt,
@@ -318,7 +317,6 @@ def trace_production_kernels() -> dict[str, object]:
                     solver._e_floor, meta,
                     t_min=solver._ti_floor, m_min=solver._mi_floor,
                 ),
-                zeros_t, zeros_t, np.zeros(Mp, np.int32),
             ),
             "express_patch": jax.make_jaxpr(
                 lambda u, w, tv, s, a, l, r, c, d: res._express_patch(

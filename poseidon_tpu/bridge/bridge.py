@@ -167,6 +167,9 @@ class SchedulerStats:
     ``placed_wait_rounds`` counts, over the pods this round placed, the
     rounds each had waited (the sum of their ``wait_rounds``): divided
     by ``pods_placed`` it is the mean queueing in rounds.
+    ``auction_rounds`` is the dense auction's bidding rounds for the
+    round's solve (``ResidentOutcome.rounds``), 0 when the oracle
+    solved it.
     """
 
     round_num: int = 0
@@ -252,6 +255,7 @@ class SchedulerStats:
     posts: int = 0
     finish_ms: float = 0.0
     placed_wait_rounds: int = 0
+    auction_rounds: int = 0
 
 
 @dataclasses.dataclass
@@ -1643,23 +1647,15 @@ class SchedulerBridge:
             machine_mem_free=self.knowledge.machine_mem_free(
                 machine_names
             ),
+            # the CURRENT packing, in both modes: occupancy (running
+            # tasks per machine) is what Octopus prices, so a place-only
+            # round spreads onto idle machines, and what makes a drifted
+            # machine expensive and a migration worth its hysteresis in
+            # a rebalancing one. Only Octopus reads it; every other
+            # model prices alike with or without it. From the
+            # graph builder's columns, O(M) numpy, no walk of the tasks.
+            machine_used_slots=cols.running_per_machine(),
         )
-        if self.enable_preemption:
-            # rebalancing needs the models to see the CURRENT packing:
-            # occupancy (running tasks per machine) is what makes a
-            # drifted machine expensive and a migration worth its
-            # hysteresis. Gated on the flag so place-only pricing stays
-            # byte-identical to the pre-rebalancing scheduler. Derived
-            # from the merged builder columns (current_m), not a Python
-            # walk of cluster.tasks — this path is O(churn) + numpy.
-            cur = cols.current_m
-            cost_kwargs["machine_used_slots"] = (
-                np.bincount(
-                    cur[cur >= 0], minlength=len(machine_names)
-                ).astype(np.int32)
-                if cur is not None
-                else np.zeros(len(machine_names), np.int32)
-            )
         with span("dispatch", stats, "dispatch_ms", round=self.round_num):
             solve = self.solver.begin_round(
                 arrays, meta,
@@ -1785,6 +1781,7 @@ class SchedulerBridge:
         )
         stats.fetch_wait_ms = outcome.timings.get("fetch_wait_ms", 0.0)
         stats.backend = outcome.backend
+        stats.auction_rounds = outcome.rounds
         stats.cost = outcome.cost
         # oversize/uncertified degrades are OBSERVABLE, not just
         # logged: a DEGRADE trace event + the lifetime counter in

@@ -95,7 +95,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--max_sample_queue_size", type=int, default=100)
     p.add_argument("--run_incremental_scheduler",
                    default="true", choices=["true", "false"],
-                   help="reuse on-HBM warm state across rounds")
+                   help="keep each round's on-HBM dense state for the "
+                        "express and stream lanes (full rounds start "
+                        "cold from the analytic clearing)")
     p.add_argument("--round_pipeline",
                    default="true", choices=["true", "false"],
                    help="ignored, kept so existing flagfiles parse: "

@@ -176,6 +176,19 @@ class BuilderColumns:
     current_m: np.ndarray | None = None   # int32[T]
     pref_d: np.ndarray | None = None      # int32[Ep]
 
+    def running_per_machine(self) -> np.ndarray:
+        """int32[M] RUNNING tasks on each machine of the MERGED columns,
+        in either mode: the place-only ``used_slots`` (which discount
+        capacity), plus the merged running block's machines
+        (``current_m``), which hold their seats through their own flow
+        and leave ``used_slots`` at 0 in rebalancing mode. The occupancy
+        the cost models price (``CostInputs.machine_used_slots``)."""
+        run = self.used_slots
+        if self.current_m is not None:
+            cur = self.current_m
+            run = run + np.bincount(cur[cur >= 0], minlength=len(run))
+        return run.astype(np.int32)
+
 
 class FlowGraphBuilder:
     """Builds the MCMF instance for one scheduling round.

@@ -334,14 +334,6 @@ class ShadowAuditor:
         usage = dict(zip(snap.uids, snap.task_usage))
         load = dict(zip(snap.names, snap.machine_load))
         memf = dict(zip(snap.names, snap.machine_mem_free))
-        cur = cols.current_m
-        used = (
-            np.bincount(
-                cur[cur >= 0], minlength=len(meta.machine_names)
-            ).astype(np.int32)
-            if cur is not None
-            else np.zeros(len(meta.machine_names), np.int32)
-        )
         from poseidon_tpu.graph.network import pad_bucket
 
         # pricing shapes ride grow-only bucketed floors (the solver's
@@ -376,7 +368,7 @@ class ShadowAuditor:
             machine_mem_free=np.array(
                 [memf[n] for n in meta.machine_names]
             ),
-            machine_used_slots=used,
+            machine_used_slots=cols.running_per_machine(),
         )
         cost = _price_on_cpu(snap.cost_model, inputs, meta.n_arcs)
         topo = extract_topology(

@@ -38,9 +38,10 @@ HBM budget, or an uncertified solve degrades to the C++ CPU oracle —
 one extra download of the priced arc table, only on the rare round
 that needs it. One deliberate divergence from ``solve_scheduling``:
 non-taxonomy graphs go straight to the oracle here rather than the
-general JAX backend, because the resident path's whole value — warm
-on-HBM state across rounds — does not exist for them (the front door
-owns the general-graph JAX lane).
+general JAX backend, because the resident path's whole value — the
+round's state kept on HBM for the lanes that patch it between rounds —
+does not exist for them (the front door owns the general-graph JAX
+lane).
 """
 
 from __future__ import annotations
@@ -764,22 +765,17 @@ class _AsyncFetch:
     jax.jit,
     static_argnames=(
         "model_fn", "n_prefs", "smax", "alpha", "max_rounds",
-        "warm_start",
     ),
 )
 def _resident_chain(
     dt: DenseTopology,
     inputs_dev,
-    warm_asg,
-    warm_lvl,
-    warm_floor,
     *,
     model_fn,
     n_prefs: int,
     smax: int,
     alpha: int,
     max_rounds: int,
-    warm_start: bool,
 ):
     """The WHOLE resident round as ONE compiled program: cost model →
     densify → eps-ladder auction → channel/objective finalize.
@@ -788,10 +784,15 @@ def _resident_chain(
     programs (model, redensify, solve, finalize) with one: three fewer
     launches per round, and no intermediate result leaves the device.
 
-    ``model_fn``/``warm_start`` are static: one compiled variant per
-    (cost model, cold/warm) pair per shape bucket. When
-    ``warm_start`` is False the warm_* arrays are ignored (pass
-    zeros).
+    ``model_fn`` is static: one compiled variant per cost model per
+    shape bucket. Every round starts cold, from the analytic clearing
+    (``cold_start`` + ``analytic_init``): on the rounds measured
+    (Quincy bursts and churned flagship rounds, place-only Octopus
+    waves of identical pods) it settles in as few auction rounds as a
+    start from the last round's prices or fewer, and a carried start
+    can strand the fuse where the cold one does not. The round's final
+    state is still kept (``ResidentSolver._warm``) for the express and
+    stream lanes, which patch it between full rounds.
     """
     # named scopes: each stage's HLO carries its name in its op_name
     # metadata (pricing / redensify / auction / finalize)
@@ -802,18 +803,11 @@ def _resident_chain(
             dt, cost, n_prefs=n_prefs, smax=smax
         )
     with jax.named_scope("auction"):
-        if warm_start:
-            asg, lvl, floor, gap, converged, rounds, phases, _ = _solve(
-                dev, warm_asg, warm_lvl, warm_floor, jnp.int32(1),
-                alpha=alpha, max_rounds=max_rounds, smax=smax,
-                analytic_init=False,
-            )
-        else:
-            asg0, lvl0, floor0, eps0 = cold_start(dev, alpha)
-            asg, lvl, floor, gap, converged, rounds, phases, _ = _solve(
-                dev, asg0, lvl0, floor0, eps0, alpha=alpha,
-                max_rounds=max_rounds, smax=smax, analytic_init=True,
-            )
+        asg0, lvl0, floor0, eps0 = cold_start(dev, alpha)
+        asg, lvl, floor, gap, converged, rounds, phases, _ = _solve(
+            dev, asg0, lvl0, floor0, eps0, alpha=alpha,
+            max_rounds=max_rounds, smax=smax, analytic_init=True,
+        )
     with jax.named_scope("finalize"):
         ch, primal = _finalize(dev, dt, pc_s, ra_s, asg)
         chosen, alt = _decision_stats(dev, asg)
@@ -841,7 +835,7 @@ class ResidentOutcome:
     cost: int                # exact unscaled objective
     backend: str             # "dense_auction" | "oracle:<why>"
     converged: bool
-    rounds: int
+    rounds: int              # auction rounds; 0 from the oracle
     phases: int
     # None only on a non-taxonomy graph (oracle path); without it the
     # outcome cannot be flow-decomposed
@@ -880,12 +874,9 @@ class InflightSolve:
     meta: GraphMeta | None = None
     topo: TransportTopology | None = None
     dt: object = None                # device DenseTopology
-    inputs_dev: object = None
     model_fn: object = None
     n_prefs: int = 0
     smax: int = 1
-    max_rounds: int = 0
-    warm_used: bool = False
     Tp: int = 0
     Mp: int = 0
     T: int = 0
@@ -1332,15 +1323,15 @@ class ResidentSolver:
     ) -> None:
         """Replay/restore seeding: restore recorded padding floors and
         (optionally) upload a recorded warm (asg, lvl, floor) mirror
-        as the next round's warm start — the next round then runs the
-        exact compiled program the recorded round ran, from the same
-        starting state, so assignment/cost are bit-identical and the
-        restored floors keep the steady state at zero recompiles. Two
-        callers, both OFF the round's hot path: the offline replay
-        harness (obs/replay.py) and the startup warm restore
-        (ha/checkpoint.restore_bridge — the crash-safety layer's
-        whole point is that a restarted daemon re-enters here instead
-        of a cold solve)."""
+        as the live warm state. The next full round then runs the
+        exact compiled program the recorded round ran (it starts cold
+        from the analytic clearing whatever the warm state), so
+        assignment/cost are bit-identical and the restored floors keep
+        the steady state at zero recompiles; the express and stream
+        lanes patch the uploaded state until that round. Two callers,
+        both OFF the round's hot path: the offline replay harness
+        (obs/replay.py) and the startup warm restore
+        (ha/checkpoint.restore_bridge)."""
         if floors:
             self._e_floor = floors["e"]
             self._t_floor = floors["t"]
@@ -1368,9 +1359,9 @@ class ResidentSolver:
             )
         else:
             asg_d, lvl_d, floor_d = jax.device_put((asg, lvl, floor))
-        # gap/converged/rounds/phases are never read on the warm-start
-        # path (_resident_chain consumes asg/lvl/floor only), so int32
-        # placeholders avoid an x64-mode dependency here
+        # gap/converged/rounds/phases are never read from a warm state
+        # (the express and stream chains consume asg/lvl/floor only),
+        # so int32 placeholders avoid an x64-mode dependency here
         self._warm = DenseState(
             asg=asg_d, lvl=lvl_d, floor=floor_d,
             gap=jnp.int32(0), converged=jnp.asarray(True),
@@ -1669,21 +1660,11 @@ class ResidentSolver:
         # download an explicit sanctioned device_get on the fetch
         # thread — any other host sync slipping into this window
         # raises instead of silently re-adding a per-round sync.
-        warm = self._warm
-        if warm is not None and (
-            warm.asg.shape[0] != Tp or warm.floor.shape[0] != Mp
-        ):
-            warm = None  # cluster outgrew its padding bucket
         max_rounds = (
             self.max_rounds if self.max_rounds is not None
             else default_fuse()
         )
         model_fn = get_cost_model(cost_model)
-        # argument prep OUTSIDE the guard: jnp.zeros eagerly uploads
-        # its fill scalar (an implicit h2d the guard would reject);
-        # shapes are bucketed so these hit jax's cache in steady state
-        zeros_t = jnp.zeros(Tp, I32)
-        zeros_m = jnp.zeros(Mp, I32)
 
         with no_implicit_transfers():
             with span("upload", timings, "upload_ms"):
@@ -1715,12 +1696,8 @@ class ResidentSolver:
                  cost_dev, dev_inst) = (
                     _resident_chain(
                         dt, inputs_dev,
-                        warm.asg if warm is not None else zeros_t,
-                        warm.lvl if warm is not None else zeros_t,
-                        warm.floor if warm is not None else zeros_m,
                         model_fn=model_fn, n_prefs=P, smax=smax,
                         alpha=self.alpha, max_rounds=max_rounds,
-                        warm_start=warm is not None,
                     )
                 )
             state = DenseState(
@@ -1757,12 +1734,9 @@ class ResidentSolver:
             meta=meta,
             topo=base_topo,
             dt=dt,
-            inputs_dev=inputs_dev,
             model_fn=model_fn,
             n_prefs=P,
             smax=smax,
-            max_rounds=max_rounds,
-            warm_used=warm is not None,
             Tp=Tp,
             Mp=Mp,
             T=T,
@@ -1801,7 +1775,7 @@ class ResidentSolver:
 
     def finish_round(self, inflight: InflightSolve) -> ResidentOutcome:
         """Join the async placement fetch and complete the round
-        (certificate checks, cold retry, warm-state commit)."""
+        (certificate checks, warm-state commit)."""
         if inflight.outcome is not None:
             return inflight.outcome
         self._inflight = False
@@ -1842,50 +1816,6 @@ class ResidentSolver:
                 inflight.arrays, inflight.meta, topo, inflight.cost_dev,
                 timings, why="cost-domain",
             )
-        if not bool(conv) and inflight.warm_used:
-            # stale warm start stranded the eps=1 settle: retry cold
-            # (its solve + second download land in the same timing
-            # columns — this round really does pay twice). Synchronous:
-            # the overlap window is gone by the time we know.
-            self._warm = None
-            t0 = time.perf_counter()
-            # zeros outside the guard: their fill-scalar upload is an
-            # implicit h2d (see begin_round)
-            zeros_t = jnp.zeros(inflight.Tp, I32)
-            zeros_m = jnp.zeros(inflight.Mp, I32)
-            with no_implicit_transfers():
-                with enable_x64(True):
-                    (asg_d, lvl_d, floor_d, gap_d, conv_d, rounds_d,
-                     phases_d, ch_dev, primal, _dom, chosen_d, alt_d,
-                     cost_dev, dev_inst) = (
-                        _resident_chain(
-                            inflight.dt, inflight.inputs_dev, zeros_t,
-                            zeros_t, zeros_m,
-                            model_fn=inflight.model_fn,
-                            n_prefs=inflight.n_prefs,
-                            smax=inflight.smax,
-                            alpha=self.alpha,
-                            max_rounds=inflight.max_rounds,
-                            warm_start=False,
-                        )
-                    )
-                state = DenseState(
-                    asg=asg_d, lvl=lvl_d, floor=floor_d, gap=gap_d,
-                    converged=conv_d, rounds=rounds_d, phases=phases_d,
-                )
-            inflight.cost_dev = cost_dev
-            inflight.dev = dev_inst
-            self.last_round_fetches += 1
-            with sanctioned_transfer():
-                (asg_np, ch_np, conv, rounds, phases, primal_np,
-                 chosen_np, alt_np, lvl_np, floor_np) = (
-                    jax.device_get((  # noqa: PTA001 -- sanctioned second fetch of the cold retry (this round really does pay twice)
-                        state.asg, ch_dev, state.converged, state.rounds,
-                        state.phases, primal, chosen_d, alt_d,
-                        state.lvl, state.floor,
-                    ))
-                )
-            timings["solve_ms"] += (time.perf_counter() - t0) * 1000
         if not bool(conv):
             self._warm = None
             return self._oracle_round(

@@ -18,7 +18,7 @@ import jax
 import numpy as np
 import pytest
 from jax import enable_x64
-from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from poseidon_tpu.graph.builder import FlowGraphBuilder
 from poseidon_tpu.graph.network import pad_bucket
@@ -97,15 +97,13 @@ def flagship():
 
 
 def _resident_args(f, sharding):
-    zeros_t = jax.ShapeDtypeStruct((f.Tp,), np.int32, sharding=sharding)
-    zeros_m = jax.ShapeDtypeStruct((f.Mp,), np.int32, sharding=sharding)
-    return (_shapes(f.dt, sharding), _shapes(f.inputs, sharding), zeros_t, zeros_t, zeros_m)
+    return (_shapes(f.dt, sharding), _shapes(f.inputs, sharding))
 
 
-def _resident_kw(f, warm_start=False):
+def _resident_kw(f):
     return dict(
         model_fn=f.model_fn, n_prefs=f.P, smax=f.smax, alpha=ALPHA,
-        max_rounds=default_fuse(), warm_start=warm_start,
+        max_rounds=default_fuse(),
     )
 
 
@@ -193,15 +191,10 @@ def test_sharded_round_compiles_over_four_chips(flagship, topo, no_cache):
     f = flagship
     mesh = Mesh(np.array(topo.devices[:4]), ("tasks",))
     in_spec, dt_spec = resident_round_shardings(mesh, f.dt)
-    task = NamedSharding(mesh, PartitionSpec("tasks"))
-    repl = NamedSharding(mesh, PartitionSpec())
     dt = jax.tree_util.tree_map(lambda x, sh: _shapes(x, sh), f.dt, dt_spec)
-    warm_t = jax.ShapeDtypeStruct((f.Tp,), np.int32, sharding=task)
     with enable_x64(True):
         compiled = res._resident_chain.lower(
-            dt, _shapes(f.inputs, in_spec), warm_t, warm_t,
-            jax.ShapeDtypeStruct((f.Mp,), np.int32, sharding=repl),
-            **_resident_kw(f),
+            dt, _shapes(f.inputs, in_spec), **_resident_kw(f),
         ).compile()
     txt = compiled.as_text()
     assert "all-reduce" in txt and "all-gather" in txt

@@ -25,7 +25,10 @@ Mirrors src/apiclient/k8s_api_client.{h,cc}: GET ``nodes`` / ``pods``
   page — and a dropped page reads as "those pods/nodes are gone", which
   would mass-evict scheduler state. A page fetch that still fails after
   retries raises instead of returning the partial list for the same
-  reason.
+  reason;
+- an item unchanged since the previous complete LIST returns the object
+  parsed from it then, not a fresh parse (``_list_parsed``), so a poll
+  costs parse work for its churn, not for the whole cluster.
 
 Transport is stdlib urllib on purpose: the control plane is a few small
 JSON GETs per 10-second tick (deploy/poseidon.cfg / --polling_frequency),
@@ -158,6 +161,16 @@ def parse_memory_kb(q: str | int) -> int:
     return int(q) >> 10  # bare bytes
 
 
+def _node_key(item: dict) -> str:
+    return item["metadata"]["name"]
+
+
+def _pod_key(item: dict) -> str:
+    """A pod item's ``Task.uid`` (``_parse_pod``'s ``ns/name``)."""
+    meta = item["metadata"]
+    return f"{meta.get('namespace', 'default')}/{meta['name']}"
+
+
 class K8sApiClient:
     """Five operations against one base URI (k8s_api_client.h:44-48)."""
 
@@ -187,6 +200,11 @@ class K8sApiClient:
             RETRY_CLASSES, 0
         )
         self._stats_lock = threading.Lock()
+        # per (resource, selector): key -> (decoded item, parsed object)
+        # of the last complete LIST (``_list_parsed``)
+        self._held: dict[
+            tuple[str, str], dict[str, tuple[dict, object]]
+        ] = {}
         log.info("k8s api client -> %s", self.base)
 
     # ---- transport -----------------------------------------------------
@@ -262,9 +280,6 @@ class K8sApiClient:
             if isinstance(last, urllib.error.HTTPError) else 0,
         ) from last
 
-    def _list(self, resource: str, selector: str = "") -> list[dict]:
-        return self._list_rv(resource, selector)[0]
-
     def _list_rv(
         self, resource: str, selector: str = ""
     ) -> tuple[list[dict], int]:
@@ -315,16 +330,53 @@ class K8sApiClient:
             token = next_token
         raise ApiError(f"{resource}: pagination exceeded {max_pages} pages")
 
+    def _list_parsed(
+        self, resource: str, selector: str = ""
+    ) -> tuple[list, int]:
+        """One LIST parsed into ``Machine``s or ``Task``s, plus its
+        ``resourceVersion``.
+
+        Unchanged items keep their parsed object: per ``(resource,
+        selector)`` the client holds the previous complete LIST's
+        decoded item and parsed object under the object's key (a node's
+        name, a pod's ``ns/name`` uid). An item ``==`` to the held one
+        returns the SAME frozen object without parsing it again; any
+        other item is parsed as usual. The held items are replaced only
+        once a LIST completes, so a failed or truncated LIST (which
+        raises in ``_list_rv``) leaves them as they were, and keys
+        absent from the new LIST drop out. The bridge's observe reads
+        that identity as "unchanged since the last poll"
+        (``SchedulerBridge.observe_pods``). Unparseable items are
+        logged and skipped, and never held.
+        """
+        if resource == "nodes":
+            kind, parse, key = "node", self._parse_node, _node_key
+        else:
+            kind, parse, key = "pod", self._parse_pod, _pod_key
+        items, rv = self._list_rv(resource, selector)
+        held = self._held.get((resource, selector), {})
+        fresh: dict[str, tuple[dict, object]] = {}
+        out = []
+        for item in items:
+            try:
+                k = key(item)
+                hit = held.get(k)
+                if hit is not None and hit[0] == item:
+                    obj = hit[1]
+                else:
+                    obj = parse(item)
+            except (KeyError, ValueError) as e:
+                log.error("skipping unparseable %s: %s", kind, e)
+                continue
+            fresh[k] = (item, obj)
+            out.append(obj)
+        self._held[(resource, selector)] = fresh
+        return out, rv
+
     # ---- nodes ---------------------------------------------------------
 
     def nodes_with_label(self, selector: str = "") -> list[Machine]:
-        out = []
-        for item in self._list("nodes", selector):
-            try:
-                out.append(self._parse_node(item))
-            except (KeyError, ValueError) as e:
-                log.error("skipping unparseable node: %s", e)
-        return out
+        return self._list_parsed("nodes", selector)[0]
 
     def all_nodes(self) -> list[Machine]:
         return self.nodes_with_label("")
@@ -332,14 +384,7 @@ class K8sApiClient:
     def nodes_with_rv(self) -> tuple[list[Machine], int]:
         """Full node list plus the list's ``resourceVersion`` — the
         snapshot+rv pair a watch stream continues from."""
-        items, rv = self._list_rv("nodes")
-        out = []
-        for item in items:
-            try:
-                out.append(self._parse_node(item))
-            except (KeyError, ValueError) as e:
-                log.error("skipping unparseable node: %s", e)
-        return out, rv
+        return self._list_parsed("nodes")
 
     @staticmethod
     def _parse_node(item: dict) -> Machine:
@@ -368,27 +413,14 @@ class K8sApiClient:
     # ---- pods ----------------------------------------------------------
 
     def pods_with_label(self, selector: str = "") -> list[Task]:
-        out = []
-        for item in self._list("pods", selector):
-            try:
-                out.append(self._parse_pod(item))
-            except (KeyError, ValueError) as e:
-                log.error("skipping unparseable pod: %s", e)
-        return out
+        return self._list_parsed("pods", selector)[0]
 
     def all_pods(self) -> list[Task]:
         return self.pods_with_label("")
 
     def pods_with_rv(self) -> tuple[list[Task], int]:
         """Full pod list plus the list's ``resourceVersion``."""
-        items, rv = self._list_rv("pods")
-        out = []
-        for item in items:
-            try:
-                out.append(self._parse_pod(item))
-            except (KeyError, ValueError) as e:
-                log.error("skipping unparseable pod: %s", e)
-        return out, rv
+        return self._list_parsed("pods")
 
     @staticmethod
     def _parse_pod(item: dict) -> Task:

@@ -169,7 +169,10 @@ class SchedulerStats:
     by ``pods_placed`` it is the mean queueing in rounds.
     ``auction_rounds`` is the dense auction's bidding rounds for the
     round's solve (``ResidentOutcome.rounds``), 0 when the oracle
-    solved it.
+    solved it. ``observe_listed`` counts the nodes and pods the poll
+    snapshots (``observe_nodes`` / ``observe_pods``) handed over since
+    the previous round, and ``observe_elided`` those of them whose
+    upsert was elided as unchanged.
     """
 
     round_num: int = 0
@@ -256,6 +259,8 @@ class SchedulerStats:
     finish_ms: float = 0.0
     placed_wait_rounds: int = 0
     auction_rounds: int = 0
+    observe_listed: int = 0
+    observe_elided: int = 0
 
 
 @dataclasses.dataclass
@@ -422,6 +427,14 @@ class SchedulerBridge:
         # per-round accumulators surfaced in SchedulerStats: observe
         # host time and watch degradation counts since the last round
         self._observe_ms = 0.0
+        # the poll snapshot's upsert elision (observe_nodes /
+        # observe_pods): per key, the object the last snapshot's upsert
+        # received, the object it stored, and the sample it pushed; and
+        # the objects observed and elided since the last round
+        self._node_seen: dict[str, tuple] = {}
+        self._pod_seen: dict[str, tuple] = {}
+        self._observe_listed = 0
+        self._observe_elided = 0
         # accumulators the cli loop feeds (its poseidon/list and
         # poseidon/post_bindings spans add here; SchedulerStats
         # documents the windows): LIST ms, moved into stats at begin;
@@ -570,10 +583,11 @@ class SchedulerBridge:
 
     # ---- observation (the poll side) -----------------------------------
 
-    def _upsert_node(self, node: Machine) -> str:
+    def _upsert_node(self, node: Machine) -> MachineSample:
         """One node's upsert: state, churn notes, knowledge sample.
         Shared by the poll snapshot diff and the watch event path so
-        both drive identical transitions. Returns the node name."""
+        both drive identical transitions. Returns the sample it
+        pushed."""
         g = self._graph
         if node.max_tasks <= 0:
             node = dataclasses.replace(
@@ -591,16 +605,12 @@ class SchedulerBridge:
         self.machines[node.name] = node
         cap = max(node.cpu_capacity, 1e-9)
         mem_cap = max(node.memory_capacity_kb, 1)
-        self.knowledge.add_machine_sample(
-            node.name,
-            MachineSample(
-                cpu_idle=min(node.cpu_allocatable / cap, 1.0),
-                mem_free_frac=min(
-                    node.memory_allocatable_kb / mem_cap, 1.0
-                ),
-            ),
+        sample = MachineSample(
+            cpu_idle=min(node.cpu_allocatable / cap, 1.0),
+            mem_free_frac=min(node.memory_allocatable_kb / mem_cap, 1.0),
         )
-        return node.name
+        self.knowledge.add_machine_sample(node.name, sample)
+        return sample
 
     def _requeue_take(self) -> bool:
         """Consume one unit of the per-round staged-requeue budget
@@ -647,7 +657,15 @@ class SchedulerBridge:
                 self._evictions_this_round += 1
 
     def observe_nodes(self, nodes: list[Machine]) -> None:
-        """Upsert machines; release the ones that disappeared."""
+        """Upsert machines; release the ones that disappeared.
+
+        A node elides its upsert when it ``is`` the object the last
+        snapshot's upsert of its name received and ``machines[name]``
+        ``is`` still the object that upsert stored: the upsert would
+        store an equal object, note nothing, and push the same sample,
+        which the elided nodes push as one batch. ``machines`` is
+        written only by ``_upsert_node``, ``_remove_node`` and
+        ``restore_state`` (which clears the record)."""
         # a snapshot diff can move anything (and node changes reshape
         # the machine axis): the on-HBM express context cannot follow
         if self.express_lane:
@@ -657,8 +675,27 @@ class SchedulerBridge:
             known_before = len(self.machines)
             known_names = set(self.machines)
             seen = set()
+            last = self._node_seen
+            record: dict[str, tuple] = {}
+            elided: list[str] = []
+            samples: list[MachineSample] = []
             for node in nodes:
-                seen.add(self._upsert_node(node))
+                name = node.name
+                seen.add(name)
+                rec = last.get(name)
+                if (rec is not None and rec[0] is node
+                        and self.machines.get(name) is rec[1]):
+                    record[name] = rec
+                    elided.append(name)
+                    samples.append(rec[2])
+                    continue
+                sample = self._upsert_node(node)
+                record[name] = (node, self.machines[name], sample)
+            self._node_seen = record
+            if elided:
+                self.knowledge.add_machine_samples(elided, samples)
+            self._observe_listed += len(nodes)
+            self._observe_elided += len(elided)
             gone = known_names - seen
             if self._hold_shrink(
                 "_node_shrink_strikes", "node", known_before, len(gone)
@@ -709,11 +746,12 @@ class SchedulerBridge:
               or known.memory_request_kb != pod.memory_request_kb):
             g.note_task_updated(stored)
 
-    def _upsert_pod(self, pod: Task) -> str:
+    def _upsert_pod(self, pod: Task) -> TaskSample | None:
         """One pod's state-machine dispatch (the reference's per-pod
         switch, scheduler_bridge.cc:132-162). Shared by the poll
         snapshot diff and the watch event path so both drive identical
-        transitions and churn notes. Returns the uid."""
+        transitions and churn notes. Returns the knowledge sample it
+        pushed (a pod adopted Running), else None."""
         g = self._graph
         known = self.tasks.get(pod.uid)
         if pod.phase == TaskPhase.PENDING:
@@ -774,7 +812,7 @@ class SchedulerBridge:
                     g.note_full_rebuild("pod re-entered pending")
                 self.tasks[pod.uid] = stored
                 self.pod_to_machine.pop(pod.uid, None)
-                return pod.uid
+                return None
             if known is None or known.machine != pod.machine:
                 # restart reconcile: adopt the apiserver's binding
                 # instead of the reference's CHECK-crash
@@ -822,13 +860,12 @@ class SchedulerBridge:
             self.tasks[pod.uid] = stored
             if pod.machine:
                 self.pod_to_machine[pod.uid] = pod.machine
-            self.knowledge.add_task_sample(
-                pod.uid,
-                TaskSample(
-                    cpu_usage=pod.cpu_request,
-                    mem_usage_kb=pod.memory_request_kb,
-                ),
+            sample = TaskSample(
+                cpu_usage=pod.cpu_request,
+                mem_usage_kb=pod.memory_request_kb,
             )
+            self.knowledge.add_task_sample(pod.uid, sample)
+            return sample
         else:  # Succeeded / Failed / Unknown: retire, free the slot
             if known is not None:
                 log.info("retiring pod %s (%s)", pod.uid, pod.phase)
@@ -842,7 +879,7 @@ class SchedulerBridge:
                 self.knowledge.retire_task(pod.uid)
                 if self.lifecycle is not None:
                     self.lifecycle.drop(pod.uid)
-        return pod.uid
+        return None
 
     def _remove_pod(self, uid: str) -> None:
         """A pod left the cluster without a terminal phase (poll: gone
@@ -858,7 +895,30 @@ class SchedulerBridge:
 
     def observe_pods(self, pods: list[Task]) -> None:
         """The reference's per-pod dispatch (scheduler_bridge.cc:132-162),
-        with restart reconcile and terminal-state retirement."""
+        with restart reconcile and terminal-state retirement.
+
+        O(churn) for an unchanged pod: it elides ``_upsert_pod`` when
+        it ``is`` the object the last snapshot's upsert of its uid
+        received (the client hands back the same frozen object for an
+        unchanged LIST item) and ``tasks.get(uid)`` ``is`` still the
+        object that upsert left there. The upsert is then a no-op but
+        for an equal stored object and its knowledge sample, and the
+        elided pods push their samples as one batch (the rings stay
+        bit-identical). The invariant this rests on: every writer of
+        ``tasks``, ``pod_to_machine`` and ``_displaced_parked`` that
+        can change an upsert's outcome stores a NEW task object —
+        ``_upsert_pod``, ``_remove_node`` (displacement), ``_age`` and
+        ``binding_failed`` (aging), ``confirm_binding``,
+        ``revoke_binding``, ``confirm_migration``,
+        ``confirm_preemption``, ``restore_running``, retirement (which
+        pops), and ``restore_state`` (which clears the record).
+        ``begin_round``'s parked admission re-inserts the same object,
+        and a pending pod's upsert notes nothing when it is unchanged,
+        parked or not. One outcome depends on state outside the pod: a
+        Running pod on an unknown node is kept Pending and is adopted
+        once the node appears, so that outcome is never recorded. An
+        elided uid still counts as seen for the mass-eviction guard
+        and the deletions."""
         if self.express_lane:
             self.solver.invalidate_express()
         with span("observe", self, "_observe_ms",
@@ -866,8 +926,34 @@ class SchedulerBridge:
             known_before = len(self.tasks)
             known_uids = set(self.tasks)
             seen = set()
+            tasks = self.tasks
+            last = self._pod_seen
+            record: dict[str, tuple] = {}
+            elided = 0
+            uids: list[str] = []
+            samples: list[TaskSample] = []
             for pod in pods:
-                seen.add(self._upsert_pod(pod))
+                uid = pod.uid
+                seen.add(uid)
+                rec = last.get(uid)
+                if (rec is not None and rec[0] is pod
+                        and tasks.get(uid) is rec[1]):
+                    record[uid] = rec
+                    elided += 1
+                    if rec[2] is not None:
+                        uids.append(uid)
+                        samples.append(rec[2])
+                    continue
+                sample = self._upsert_pod(pod)
+                stored = tasks.get(uid)
+                if (pod.phase != TaskPhase.RUNNING or stored is None
+                        or stored.phase == TaskPhase.RUNNING):
+                    record[uid] = (pod, stored, sample)
+            self._pod_seen = record
+            if uids:
+                self.knowledge.add_task_samples(uids, samples)
+            self._observe_listed += len(pods)
+            self._observe_elided += elided
             gone = known_uids - seen
             if self._hold_shrink(
                 "_pod_shrink_strikes", "pod", known_before, len(gone)
@@ -1492,6 +1578,9 @@ class SchedulerBridge:
         stats.degrades_total = self._degrades_total
         stats.observe_ms = round(self._observe_ms, 3)
         self._observe_ms = 0.0
+        stats.observe_listed = self._observe_listed
+        stats.observe_elided = self._observe_elided
+        self._observe_listed = self._observe_elided = 0
         stats.list_ms = round(self.pending_list_ms, 3)
         self.pending_list_ms = 0.0
         stats.watch_resyncs = self._watch_resyncs
@@ -2068,6 +2157,8 @@ class SchedulerBridge:
         guard itself stays armed across the boundary)."""
         self.machines = {m.name: m for m in machines}
         self.tasks = {t.uid: t for t in tasks}
+        self._node_seen = {}
+        self._pod_seen = {}
         self.pod_to_machine = {
             t.uid: t.machine for t in tasks
             if t.phase == TaskPhase.RUNNING

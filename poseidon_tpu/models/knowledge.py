@@ -109,6 +109,29 @@ class _RingStore:
             self._buf[f, row, slot] = v32
         self._count[row] += 1
 
+    def add_many(self, names: list[str], values: np.ndarray) -> None:
+        """``add`` for each name in turn, with ``values[f, i]`` the
+        i-th name's field ``f``: the same float32 slots, float64 sums
+        and counts, bit for bit, in vectorized numpy. A name given
+        twice falls back to ``add`` one by one (a fancy-indexed update
+        would keep only one of its samples)."""
+        rows = np.fromiter(
+            (self._row(name) for name in names), np.int64, len(names)
+        )
+        if np.unique(rows).size != rows.size:
+            for name, vals in zip(names, values.T.tolist()):
+                self.add(name, *vals)
+            return
+        slot = self._count[rows] % self.queue_size
+        for f in range(self.n_fields):
+            v32 = values[f].astype(np.float32)
+            old = self._buf[f, rows, slot]
+            self._sum[f, rows] += (
+                v32.astype(np.float64) - old.astype(np.float64)
+            )
+            self._buf[f, rows, slot] = v32
+        self._count[rows] += 1
+
     def export_state(self) -> dict:
         """Host-array snapshot for checkpointing (ha/checkpoint.py).
 
@@ -180,6 +203,26 @@ class KnowledgeBase:
 
     def add_task_sample(self, uid: str, sample: TaskSample) -> None:
         self._tasks.add(uid, sample.cpu_usage, float(sample.mem_usage_kb))
+
+    def add_machine_samples(
+        self, names: list[str], samples: list[MachineSample]
+    ) -> None:
+        """``add_machine_sample`` for each pair, in one vectorized
+        update (bit-identical; see ``_RingStore.add_many``)."""
+        self._machines.add_many(names, np.array(
+            [[s.cpu_idle for s in samples],
+             [s.mem_free_frac for s in samples]], np.float64,
+        ).reshape(2, len(samples)))
+
+    def add_task_samples(
+        self, uids: list[str], samples: list[TaskSample]
+    ) -> None:
+        """``add_task_sample`` for each pair, in one vectorized
+        update (bit-identical; see ``_RingStore.add_many``)."""
+        self._tasks.add_many(uids, np.array(
+            [[s.cpu_usage for s in samples],
+             [float(s.mem_usage_kb) for s in samples]], np.float64,
+        ).reshape(2, len(samples)))
 
     def retire_task(self, uid: str) -> None:
         """Drop a retired pod's ring (called when the bridge retires it)."""

@@ -8,6 +8,9 @@ evict most of the scheduler's state. These tests pin both defenses:
 
 - the client follows ``metadata.continue`` tokens until the list is
   complete (round-3 verdict, Next #7);
+- an item unchanged since the previous complete list returns the object
+  parsed from it then; a changed one, a failed list or a deleted key
+  does not reuse it;
 - the bridge holds a >50% disappearance for ``SHRINK_STRIKES``
   consecutive polls before honoring it, and still honors a persistent
   (real) shrink afterwards.
@@ -15,7 +18,10 @@ evict most of the scheduler's state. These tests pin both defenses:
 
 from __future__ import annotations
 
+import pytest
+
 from poseidon_tpu.apiclient import FakeApiServer, K8sApiClient
+from poseidon_tpu.apiclient.client import ApiError
 from poseidon_tpu.bridge import SchedulerBridge
 from poseidon_tpu.bridge.bridge import SHRINK_STRIKES
 from poseidon_tpu.cluster import TaskPhase
@@ -58,6 +64,105 @@ class TestClientPagination:
         assert sorted(n.name for n in rack0) == sorted(
             f"node-{i:03d}" for i in range(12) if i % 3 == 0
         )
+
+
+def _by_key(objs):
+    return {getattr(o, "uid", None) or o.name: o for o in objs}
+
+
+class TestParsedItemReuse:
+    """``K8sApiClient._list_parsed``: an item ``==`` to the previous
+    complete list's returns the SAME frozen object (the bridge reads
+    that identity as "unchanged"); anything else is parsed afresh."""
+
+    @pytest.mark.parametrize("resource", ["nodes", "pods"])
+    def test_unchanged_items_return_the_identical_object(self, resource):
+        with FakeApiServer() as server:
+            _fill(server, nodes=12, pods=12)
+            client = K8sApiClient(port=server.port, page_limit=5)
+            first = getattr(client, f"all_{resource}")()
+            again, _rv = getattr(client, f"{resource}_with_rv")()
+        assert len(first) == len(again) == 12
+        assert all(x is y for x, y in zip(first, again))
+
+    @pytest.mark.parametrize(
+        "change", ["nodeName", "phase", "annotation", "resourceVersion"])
+    def test_a_changed_pod_item_is_parsed_afresh(self, change):
+        with FakeApiServer() as server:
+            _fill(server, nodes=3, pods=6)
+            client = K8sApiClient(port=server.port, page_limit=4)
+            first = _by_key(client.all_pods())
+            uid = "default/pod-002"
+            if change == "nodeName":
+                assert client.bind_pod_to_node(uid, "node-001")
+            elif change == "phase":
+                server.succeed_pod("pod-002")
+            elif change == "annotation":
+                server.add_pod("pod-002", data_prefs={"node-000": 5})
+            else:
+                server.pods[uid]["metadata"]["resourceVersion"] = "7"
+            again = _by_key(client.all_pods())
+        assert again[uid] is not first[uid]
+        if change == "resourceVersion":
+            assert again[uid] == first[uid]     # an equal, fresh parse
+        else:
+            assert again[uid] != first[uid]
+        assert all(again[k] is first[k] for k in first if k != uid)
+
+    def test_a_changed_node_item_is_parsed_afresh(self):
+        with FakeApiServer() as server:
+            _fill(server, nodes=6, pods=0)
+            client = K8sApiClient(port=server.port, page_limit=4)
+            first = _by_key(client.all_nodes())
+            server.add_node("node-004", pods=12, rack="rack-1")
+            again = _by_key(client.all_nodes())
+        assert again["node-004"] is not first["node-004"]
+        assert again["node-004"].max_tasks == 12
+        assert all(again[k] is first[k] for k in first if k != "node-004")
+
+    @pytest.mark.parametrize("resource", ["nodes", "pods"])
+    def test_a_list_failing_mid_pages_leaves_the_held_items(
+            self, resource):
+        with FakeApiServer() as server:
+            _fill(server, nodes=12, pods=12)
+            client = K8sApiClient(port=server.port, page_limit=5,
+                                  retries=0)
+            listing = getattr(client, f"all_{resource}")
+            first = listing()
+            request = client._request
+            calls = []
+
+            def second_page_fails(path, *a, **kw):
+                calls.append(path)
+                if len(calls) == 2:
+                    raise ApiError("injected", code=503)
+                return request(path, *a, **kw)
+
+            client._request = second_page_fails
+            with pytest.raises(ApiError):
+                listing()
+            client._request = request
+            again = listing()
+        assert len(calls) == 2
+        assert all(x is y for x, y in zip(first, again))
+
+    @pytest.mark.parametrize("resource", ["nodes", "pods"])
+    def test_a_deleted_key_leaves_the_held_items(self, resource):
+        with FakeApiServer() as server:
+            _fill(server, nodes=4, pods=4)
+            client = K8sApiClient(port=server.port)
+            listing = getattr(client, f"all_{resource}")
+            store = server.nodes if resource == "nodes" else server.pods
+            key = next(iter(store))
+            first = _by_key(listing())
+            item = store.pop(key)
+            name = key.split("/")[-1]
+            assert name not in {k.split("/")[-1]
+                                for k in _by_key(listing())}
+            store[key] = item             # back, unchanged
+            again = _by_key(listing())
+        k = next(k for k in again if k.split("/")[-1] == name)
+        assert again[k] == first[k] and again[k] is not first[k]
 
 
 class TestMassEvictionGuard:

@@ -7,8 +7,8 @@ single-threaded with documented cross-thread handoffs — but a contract
 nobody checks is a comment. This package makes them machine-checked:
 
 - ``python -m poseidon_tpu.analysis`` runs every registered rule over
-  the shipped tree (``poseidon_tpu/``, ``bench.py``, ``scripts/``,
-  and ``tests/`` under a narrowed per-rule scope) and exits non-zero
+  the shipped tree (``poseidon_tpu/``, ``scripts/``, and
+  ``tests/`` under a narrowed per-rule scope) and exits non-zero
   on any violation; CI runs it as a blocking step (with
   ``--audit-suppressions``, so DEAD ``# noqa`` comments fail too).
 - Rules are repo-specific, declared against ``contracts.py``. The

@@ -86,7 +86,7 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument(
         "paths", nargs="*",
         help="files to scan (default: the shipped tree — "
-             "poseidon_tpu/, scripts/, tests/, bench.py)",
+             "poseidon_tpu/, scripts/, tests/)",
     )
     p.add_argument(
         "--format", choices=("human", "json"), default="human",

@@ -265,14 +265,13 @@ def _apply_suppressions(
 
 
 def default_targets(root: pathlib.Path) -> list[pathlib.Path]:
-    """The shipped tree: the package, the bench harness, scripts/, and
-    tests/. Tests run under a NARROWED rule set
-    (``Contracts.path_rules``): jit hygiene and the trace/flag
-    vocabulary apply to test code too (a test leaking fresh jit
-    wrappers or emitting undeclared events is a real bug), but the
-    hot-path/O(churn)/thread rules do not — test files deliberately
-    contain seeded-violation snippets (as data) and drive private APIs
-    the contracts exempt."""
+    """The shipped tree: the package, scripts/, and tests/. Tests run
+    under a NARROWED rule set (``Contracts.path_rules``): jit hygiene
+    and the trace/flag vocabulary apply to test code too (a test
+    leaking fresh jit wrappers or emitting undeclared events is a real
+    bug), but the hot-path/O(churn)/thread rules do not — test files
+    deliberately contain seeded-violation snippets (as data) and drive
+    private APIs the contracts exempt."""
     out: list[pathlib.Path] = []
     for base in ("poseidon_tpu", "scripts", "tests"):
         d = root / base
@@ -281,10 +280,6 @@ def default_targets(root: pathlib.Path) -> list[pathlib.Path]:
                 p for p in sorted(d.rglob("*.py"))
                 if not any(part in _SKIP_DIRS for part in p.parts)
             )
-    for single in ("bench.py",):
-        p = root / single
-        if p.is_file():
-            out.append(p)
     return out
 
 

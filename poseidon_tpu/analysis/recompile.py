@@ -1,15 +1,17 @@
 """PTA007: static-arg / pad-shape provenance — the recompile-hazard pass.
 
-PR 8's bench flushed out three recompile sources at RUNTIME, all one
-bug class: a value derived from a data-dependent quantity (a ``max``
-over live state, a ``len`` of the pending pool, a topology's
-``max_prefs``) flowing into a position that pins a compiled shape — a
-``static_argnames`` argument of a jitted kernel, or a ``t_min`` /
-``m_min`` / ``p_min`` padding floor of the host padding helpers —
-WITHOUT riding a grow-only floor. The steady-state symptom is brutal
-and silent: a pending pool draining across a bucket boundary shrinks
-the derived value, the static arg changes, and every post-drain round
-pays a multi-second recompile that profiles as "the TPU got slow".
+A draining pending pool once exposed three recompile sources at
+RUNTIME (``tests/test_obs.py::TestZeroRecompileUnderDrain`` is the
+regression), all one bug class: a value derived from a data-dependent
+quantity (a ``max`` over live state, a ``len`` of the pending pool, a
+topology's ``max_prefs``) flowing into a position that pins a compiled
+shape — a ``static_argnames`` argument of a jitted kernel, or a
+``t_min`` / ``m_min`` / ``p_min`` padding floor of the host padding
+helpers — WITHOUT riding a grow-only floor. The steady-state symptom
+is brutal and silent: a pending pool draining across a bucket boundary
+shrinks the derived value, the static arg changes, and every
+post-drain round pays a multi-second recompile that profiles as "the
+TPU got slow".
 
 This pass catches the pattern at review time, as dataflow any reviewer
 can replay:
@@ -40,8 +42,8 @@ can replay:
    floor of the padding helpers (``Contracts.pad_sinks``), is the
    violation.
 
-One-shot lanes (a cold ``solve_transport_dense`` call in a test or the
-bench) recompile per call BY DESIGN — such sites carry a reasoned
+One-shot lanes (a cold ``solve_transport_dense`` call in a test or a
+script) recompile per call BY DESIGN — such sites carry a reasoned
 ``# noqa: PTA007`` so the design decision is written down where the
 reviewer reads it.
 """

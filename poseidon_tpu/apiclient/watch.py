@@ -803,12 +803,12 @@ class ClusterWatcher:
                 break
         return out
 
-    # ---- test/bench helpers ----
+    # ---- test helpers ----
 
     def wait_caught_up(self, rv: int, timeout_s: float = 5.0) -> bool:
         """Block until every stream has ENQUEUED events up to ``rv`` (or
         gone 410, which the next ``tick`` turns into a resync). Lets
-        hermetic tests and the bench make event arrival deterministic
+        hermetic tests make event arrival deterministic
         without polling ``tick`` in a loop."""
         deadline = time.monotonic() + timeout_s
         while time.monotonic() < deadline:

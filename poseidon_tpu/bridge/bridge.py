@@ -40,7 +40,7 @@ Round pipeline: the round is split into
 the placement download starts immediately on a background thread) and
 ``finish_round`` (join the fetch, apply placement deltas). Serial
 callers use ``run_scheduler`` — exactly ``finish_round(begin_round())``
-— while a pipelined driver (bench.py config 4) does next-round
+— while a pipelined caller does next-round
 host work (poll parse, observe, KnowledgeBase feed, binding POSTs of
 the previous round) between the two calls, so the device solve and
 its download elapse under host work instead of serializing after it.

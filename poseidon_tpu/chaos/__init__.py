@@ -7,8 +7,9 @@ apiserver, journal/outbox/guard all live) through seeded,
 round-scheduled fault injections and asserts the invariants that
 define "survived" — exactly-once actuation, zero lost pods, guard
 release within the grace bound, and bounded time back to a certified
-round after the fault clears. Run as fuzz in tests/test_chaos.py and
-as bench config 15 ``chaos_recovery``.
+round after the fault clears. ``tests/test_chaos.py`` runs each
+scenario, reruns it to check that recovery compiles nothing, and (slow)
+fuzzes more seeds.
 """
 
 from poseidon_tpu.chaos.scenarios import (

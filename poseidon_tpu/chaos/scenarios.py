@@ -343,7 +343,7 @@ def run_daemon_scenario(
         )
         for path in (stats_path, trace_path):
             # the daemon appends; a re-run of the same scenario in
-            # the same workdir (the bench's warm+counted passes) must
+            # the same workdir (a warm-up pass, then a counted one) must
             # start from empty evidence files
             if os.path.exists(path):
                 os.remove(path)

@@ -345,9 +345,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "actuation journal; empty = crash safety off")
     p.add_argument("--checkpoint_every", type=int, default=10,
                    help="checkpoint cadence in completed rounds; the "
-                        "in-round capture is a cheap host snapshot "
-                        "(bench config 13 pins it <2% of a round "
-                        "amortized), serialization + fsync run on a "
+                        "in-round capture is a host snapshot with no "
+                        "device sync, serialization + fsync run on a "
                         "background writer thread")
     p.add_argument("--restore", default="auto",
                    choices=["auto", "true", "false"],

@@ -15,7 +15,7 @@ Three guards, all cheap enough to stay on in production:
   allow-list visible in the code instead of implied.
 - ``CompileCounter``: counts XLA backend compiles via
   ``jax.monitoring`` so tests can assert the steady-state recompile
-  budget (zero) — a recompile regression fails tier-1, not just bench.
+  budget (zero) — a recompile regression fails tier-1.
 
 ``FetchTimeout`` is raised by the resident solver when the pipelined
 round's background placement fetch exceeds its deadline
@@ -64,7 +64,7 @@ def configure_compile_cache() -> str:
     ``JAX_COMPILATION_CACHE_DIR``, when set, places it (JAX reads the
     variable itself, so no path is set here); otherwise it lives at
     ``<repo>/.jax_cache``. Called by the entry points a user runs
-    (``cli.main``, ``bench.main``, ``chip_smoke.py``) — never by
+    (``cli.main``, ``chip_smoke.py``, ``benchmark/run.py``) — never by
     ``run_loop`` or the tests, which must not write a cache.
     """
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")

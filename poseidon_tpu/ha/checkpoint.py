@@ -132,9 +132,9 @@ def capture_snapshot(bridge, watcher=None) -> CheckpointSnapshot:
     are frozen dataclasses the bridge replaces, never mutates), the
     warm seed and builder columns are references frozen by the
     copy-on-write discipline, and only the knowledge rings — which DO
-    mutate in place — are copied. Amortized over the ``--checkpoint_
-    every`` cadence this stays inside the same <2% budget the flight
-    recorder's capture meets (bench config 13).
+    mutate in place — are copied. It issues no device sync, the
+    ``--checkpoint_every`` cadence amortizes its map copies, and
+    serialization + fsync run on the background writer thread.
     """
     solver = bridge.solver
     graph = getattr(bridge, "_graph", None)

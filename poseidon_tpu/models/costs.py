@@ -133,9 +133,8 @@ def build_cost_inputs_host(
     owning solver (the same anti-recompile hysteresis ``pad_topology``
     applies): without them, a pending pool draining across a bucket
     boundary shrinks the per-task input shapes and recompiles the
-    whole fused chain on a round whose topology padding stayed put —
-    bench config 10 (``observability_overhead``) caught exactly that
-    as a multi-second dispatch on every post-drain round.
+    whole fused chain on a round whose topology padding stayed put
+    (``tests/test_obs.py::TestZeroRecompileUnderDrain`` pins the fix).
     """
     E = arc_slots
     T = len(meta.task_uids)

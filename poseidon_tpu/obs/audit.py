@@ -56,8 +56,8 @@ written under ``_lock`` on the worker and read under it from the
 driver/scrape side. The capture helper is a PTA001 hot scope (no
 device syncs) — like the checkpoint capture it is deliberately NOT an
 O(churn) scope: the amortized-cadence O(cluster) list copy is its
-documented design (bench config 14 pins the amortized cost <2% of a
-churned-warm round).
+documented design, and the re-solve itself runs on the worker thread,
+off the round.
 """
 
 from __future__ import annotations
@@ -118,7 +118,7 @@ class AuditResult:
 class ShadowAuditor:
     """Sampled background re-solve of the live placement's quality.
 
-    ``background=False`` (tests, bench determinism) skips the worker
+    ``background=False`` (test determinism) skips the worker
     thread; ``run_pending()`` then processes captures inline.
     """
 
@@ -148,7 +148,7 @@ class ShadowAuditor:
         # private): without them every audit's slightly different
         # task/arc counts mint fresh compiled shapes on the CPU
         # backend — harmless to the round but a per-audit compile tax
-        # and noise in any zero-recompile budget (bench config 14)
+        # and noise in any zero-recompile budget
         self._t_floor = 16
         self._m_floor = 16
         self._e_floor = 256
@@ -171,8 +171,8 @@ class ShadowAuditor:
         pricing compiles ONE shape at the first sample instead of one
         per bucket crossing while a ramping cluster grows through
         them (benign background compiles, but noise in any
-        zero-recompile budget — bench config 14 calls this before
-        its measured window). ``arcs`` defaults to a generous
+        zero-recompile budget — call this before a counted window).
+        ``arcs`` defaults to a generous
         rebalancing-mode estimate from the task/machine counts."""
         from poseidon_tpu.graph.network import pad_bucket
 
@@ -265,7 +265,7 @@ class ShadowAuditor:
 
     def run_pending(self) -> AuditResult | None:
         """Synchronous mode: process every queued capture inline
-        (tests/bench determinism; returns the last result)."""
+        (test determinism; returns the last result)."""
         out = None
         while True:
             try:

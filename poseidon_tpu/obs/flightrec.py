@@ -21,10 +21,10 @@ begin/finish window, so they are registered PTA001/PTA002 hot scopes
 ``list()`` only, no device syncs, no O(cluster) Python loops. The
 warm-start seed is a host mirror the solver's ONE batched fetch
 already downloaded (ops/resident.py ``_warm_seed``) — capturing it
-moves bytes that are already on the host, never a new sync. Bench
-config 12 (``flight_recorder_overhead``) pins the whole surface under
-2% of a churned-warm round p50 with zero steady-state recompiles, the
-same methodology as config 10.
+moves bytes that are already on the host, never a new sync.
+``tests/test_flightrec.py::TestZeroInterference`` checks that recording
+changes no decision, and ``tests/test_obs.py::TestZeroRecompileUnderDrain``
+that it compiles nothing in steady state.
 
 Replay-fidelity contract: a round record carries everything the
 resident solver's compiled chain reads — the replayed round runs the

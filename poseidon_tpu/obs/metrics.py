@@ -23,8 +23,8 @@ time ONLY, from host-side values the caller already holds (stats
 fields, perf-counter durations, outcome counts) — never a device
 fetch, never an O(cluster) walk. The helpers are registered as
 PTA001/PTA002 scopes so the linter enforces that, and
-``tests/test_obs.py`` + bench config 10 (``observability_overhead``)
-prove the surface costs <2% of a flagship churned-warm round.
+``tests/test_obs.py`` checks the families and that a draining pool
+with the surface on compiles nothing in steady state.
 """
 
 from __future__ import annotations

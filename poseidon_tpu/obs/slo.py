@@ -50,8 +50,7 @@ section.
 
 ``evaluate()`` runs on the driver thread once per round: histogram
 snapshot deltas + a bounded deque of per-evaluation (good, total)
-pairs per objective — host arithmetic only, cost pinned by bench
-config 14 inside the observatory's <2% budget.
+pairs per objective — host arithmetic only.
 """
 
 from __future__ import annotations

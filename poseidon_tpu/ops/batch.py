@@ -1,22 +1,17 @@
 """Batched what-if scheduling: N perturbed variants, one call, one sync.
 
-BASELINE config 5: solve 64 cost-model variants of the same cluster —
+Solve N cost-model variants of the same cluster —
 "what would placement look like if these costs shifted" — against the
 reference's architecture of one solver fork/exec per instance
 (deploy/poseidon.cfg:8-10). Variant construction is one vmapped
 program; variant SOLVES are independent pipelined dispatches of the
 single-instance kernel with one batched fetch at the end.
 
-Why not vmap the solves too? Measured (1k machines x 4k tasks, x64):
-the vmapped lockstep ladder ran ~56 ms/instance — every variant drags
-through every other variant's phase boundaries, whose dense [B, Tp,
-Mp] passes then run batch-wide — vs ~7 ms/instance for pipelined
-independent solves (the single-instance compute), an ~8x difference.
-The batching win is amortizing the host sync and sharing the topology
-upload, not locksteping the eps ladder. (An earlier revision of this
-module claimed the lockstep form made per-instance time "a fraction of
-a single solve"; that was wrong at spec scale and is retracted —
-bench.py config 5 records the measured economics.)
+Why not vmap the solves too? A vmapped lockstep ladder drags every
+variant through every other variant's phase boundaries, whose dense
+[B, Tp, Mp] passes then run batch-wide, while a pipelined independent
+solve runs only its own. The batching win is amortizing the host sync
+and sharing the topology upload, not locksteping the eps ladder.
 
 Only cost-side arrays (c, u, w, dgen) vary per variant; topology
 (slots, task_valid) is shared. Perturbations are deterministic per

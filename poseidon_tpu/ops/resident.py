@@ -141,7 +141,8 @@ def pad_topology(
     all-absent, fill -1): the max pref count over PENDING tasks is
     data-dependent, and a multi-pref pod draining out of the pending
     pool would otherwise shrink the static ``n_prefs`` and recompile
-    the chain mid-steady-state (bench config 10 caught this one too).
+    the chain mid-steady-state
+    (``tests/test_obs.py::TestZeroRecompileUnderDrain`` pins it).
     """
     T, M = topo.n_tasks, topo.n_machines
     P = max(topo.max_prefs, p_min)
@@ -1228,7 +1229,8 @@ class ResidentSolver:
         # per-machine arrays by ITS OWN buckets of the raw counts, so
         # a draining pending pool would shrink those shapes (and
         # recompile the fused chain) even while pad_topology's floors
-        # hold — the floors travel together (bench config 10)
+        # hold — the floors travel together
+        # (tests/test_obs.py::TestZeroRecompileUnderDrain)
         self._ti_floor = 1
         self._mi_floor = 1
         self._s_floor = 1

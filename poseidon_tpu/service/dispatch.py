@@ -154,7 +154,7 @@ class TenantSolver:
     fetch and completes this tenant's round (certificate check, cold
     retry, warm-context commit, oracle degrade). The debug handles
     (``last_instance`` / ``last_assignment`` / ``last_cost_host``)
-    feed the bench/test bit-identity proofs.
+    feed the tests' bit-identity proofs.
     """
 
     def __init__(self, tenant_id: str, dispatcher: "BatchDispatcher"):
@@ -165,7 +165,7 @@ class TenantSolver:
         self.oracle_timeout_s = dispatcher.oracle_timeout_s
         self.express_fetches = 0
         # bit-identity hooks: the last round's exact solver inputs and
-        # output, host-side (tests/bench re-solve them solo)
+        # output, host-side (tests re-solve them solo)
         self.last_instance = None
         self.last_assignment = None
         self.last_cost_host = None
@@ -390,7 +390,8 @@ class BatchDispatcher:
         self._b_floor: dict[tuple, int] = {}
         self._smax_floor: dict[tuple, int] = {}
         # observability: lifetime dispatches and the last launch's
-        # compile count (0 in steady state — the bench asserts it)
+        # compile count (0 in steady state, as
+        # tests/test_service.py::TestZeroRecompile asserts)
         self.dispatches = 0
         self.last_launch_compiles = 0
 

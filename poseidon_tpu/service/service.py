@@ -14,7 +14,7 @@ fetch.
 The front door is an async request queue: ``submit(tenant_id)``
 enqueues one scheduling round and returns a ``concurrent.futures
 .Future`` resolving to that tenant's ``RoundResult``. The driver (cli
-``--serve``, bench config 11, or an embedding process) calls ``pump()``
+``--serve``, or an embedding process) calls ``pump()``
 to advance the double-buffered pipeline, the PR-1 begin/finish split
 writ multi-tenant:
 

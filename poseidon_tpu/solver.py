@@ -51,7 +51,7 @@ log = logging.getLogger(__name__)
 # Small-instance dispatch thresholds. Below this size the TPU launch
 # and sync cost is expected to exceed the whole subprocess-oracle solve
 # (a 100-task solve is ~1 round of real work). The crossover has not
-# been measured on an attached chip (ROADMAP A7); the bounds are
+# been measured on an attached chip (ROADMAP C10); the bounds are
 # conservative.
 SMALL_INSTANCE_TASKS = 256
 SMALL_INSTANCE_MACHINES = 64
@@ -113,8 +113,8 @@ def assignment_from_outcome(
 
 
 # Topology cache: repeated solves over the SAME GraphMeta object (what-
-# if sweeps, bench reps, warm re-solves over an unchanged graph) skip
-# the O(arcs) taxonomy re-validation — only the cost refill
+# if sweeps, repeated test solves, warm re-solves over an unchanged
+# graph) skip the O(arcs) taxonomy re-validation — only the cost refill
 # (``instance_from_topology``, pure vectorized numpy) runs per call.
 # Keyed by id(meta) with a weakref finalizer so entries die with their
 # meta (GraphMeta holds ndarrays and is not hashable).

@@ -1,11 +1,10 @@
-"""Synthetic cluster generators for the BASELINE benchmark ladder.
+"""Synthetic cluster generators.
 
-The reference publishes no benchmarks (SURVEY.md section 6); the driver's
-north star is the BASELINE.md config ladder (Trivial 10/100 -> Quincy
-1k/10k -> CoCo 1k -> trace replay -> vmap x64). These generators produce
-``ClusterState`` instances at those scales with realistic structure: racks
-of ~32 machines, multi-task jobs, Zipf-ish data-locality preferences, and
-a fraction of already-running tasks occupying slots.
+``make_synthetic_cluster`` produces ``ClusterState`` instances with
+realistic structure: racks of ~32 machines, multi-task jobs, Zipf-ish
+data-locality preferences, and a fraction of already-running tasks
+occupying slots. The named presets are the shapes the tests,
+``chip_smoke.py`` and the AOT compile check share.
 """
 
 from __future__ import annotations
@@ -26,7 +25,7 @@ def make_synthetic_cluster(
     tasks_per_job: int = 8,
     running_fraction: float = 0.0,
 ) -> ClusterState:
-    """A synthetic cluster shaped like the BASELINE configs.
+    """A synthetic cluster of ``n_machines`` racked machines.
 
     ``running_fraction`` of the tasks are marked RUNNING and bound to a
     machine (consuming slots via the builder's discounting); the rest are
@@ -84,36 +83,12 @@ def make_synthetic_cluster(
     return ClusterState(machines=machines, tasks=tasks)
 
 
-# ---- the BASELINE.md ladder ----
-
-def config1_trivial_small(seed: int = 0) -> ClusterState:
-    """BASELINE config 1: Trivial model, 10 nodes / 100 pods."""
-    return make_synthetic_cluster(10, 100, seed=seed, prefs_per_task=0,
-                                  max_tasks_per_machine=12)
-
+# ---- named presets ----
 
 def config2_quincy_flagship(seed: int = 0) -> ClusterState:
-    """BASELINE config 2: Quincy, 1k nodes / 10k pods (the headline)."""
+    """The Quincy flagship shape: 1k nodes / 10k pods."""
     return make_synthetic_cluster(1000, 10_000, seed=seed,
                                   prefs_per_task=2)
-
-
-def config3_coco(seed: int = 0) -> ClusterState:
-    """BASELINE config 3: CoCo interference, 1k nodes."""
-    return make_synthetic_cluster(1000, 8000, seed=seed, prefs_per_task=1,
-                                  running_fraction=0.2)
-
-
-def config5_whatif(seed: int = 0) -> ClusterState:
-    """BASELINE config 5 cluster: Quincy at 1k machines / 4k pods.
-
-    Round 3 benched what-if batching only on the config-1 toy, where
-    per-variant overhead dominates and serial CPU solves win (VERDICT
-    round 3, Weak #5). The batched-vmap capability pays off where one
-    solve is expensive and the lockstep variants amortize it — this is
-    that scale.
-    """
-    return make_synthetic_cluster(1000, 4000, seed=seed, prefs_per_task=2)
 
 
 def config6_rebalance(
@@ -122,7 +97,7 @@ def config6_rebalance(
     *,
     seed: int = 0,
 ) -> ClusterState:
-    """Config 6: a drifted cluster for the rebalancing bench.
+    """A drifted cluster for the rebalancing and shadow-audit tests.
 
     Every task is already RUNNING, crowded onto the first quarter of
     the machines (the packing a restart-adoption or a long
@@ -174,8 +149,8 @@ def config8_scale(
     n_skus: int = 2,
     max_tasks_per_machine: int = 10,
 ) -> ClusterState:
-    """Config 8 (scale_ceiling): the cluster the single-chip dense
-    table cannot hold — ROADMAP item 1's 64k machines / 512k pods.
+    """The aggregation-scale fleet: by default 64k machines / 512k
+    pods, a cluster the single-chip dense table cannot hold.
 
     Shaped like a real hyperscale fleet: a small number of hardware
     SKUs (homogeneous machines are the norm at this scale — machine
@@ -231,96 +206,3 @@ def config8_scale(
         for j in range(n_tasks)
     ]
     return ClusterState(machines=machines, tasks=tasks)
-
-
-def config8_arrivals(
-    n_racks: int,
-    n_new: int,
-    round_no: int,
-    *,
-    seed: int = 0,
-) -> list[Task]:
-    """Per-round arrival burst for the scale_ceiling churn rounds,
-    shaped like ``config8_scale``'s pods."""
-    rng = np.random.default_rng(seed + round_no)
-    home = rng.integers(0, n_racks, size=n_new)
-    weight = rng.integers(1, 4, size=n_new)
-    return [
-        Task(
-            uid=f"pod-r{round_no:03d}-{j:06d}",
-            job=f"job-r{round_no:03d}-{j // 16:05d}",
-            cpu_request=0.25,
-            memory_request_kb=1 << 18,
-            data_prefs={f"rack{int(home[j]):04d}": int(weight[j])},
-            wait_rounds=0,
-        )
-        for j in range(n_new)
-    ]
-
-
-def config4_trace_replay(
-    n_machines: int = 12_000,
-    *,
-    seed: int = 0,
-    arrivals_per_round: int = 500,
-    finish_fraction: float = 0.3,
-):
-    """BASELINE config 4: cluster-trace-style replay (12k machines).
-
-    Returns (machines, round_iter) where round_iter yields per-round
-    (new_tasks, finished_uids): a churn stream shaped like cluster-trace
-    replays — bursts of arrivals, a fraction of running work finishing
-    each round — to drive the bridge's incremental re-solve path. The
-    real Google trace is not redistributable; the statistics here (job
-    sizes, arrival burstiness) follow its published shape: many small
-    jobs, a heavy tail.
-    """
-    rng = np.random.default_rng(seed)
-    base = make_synthetic_cluster(
-        n_machines, 0, seed=seed, machines_per_rack=40,
-        max_tasks_per_machine=10,
-    )
-    machines = base.machines
-
-    def rounds():
-        counter = 0
-        running: list[str] = []
-        while True:
-            # bursty arrivals: heavy-tailed job sizes
-            n_arrive = max(1, int(rng.poisson(arrivals_per_round)))
-            new_tasks = []
-            while n_arrive > 0:
-                job_size = min(int(rng.pareto(1.5)) + 1, 64, n_arrive)
-                job = f"tracejob-{counter}"
-                for _ in range(job_size):
-                    uid = f"tracepod-{counter:07d}"
-                    counter += 1
-                    prefs = {}
-                    if rng.random() < 0.4:
-                        m = int(rng.integers(0, n_machines))
-                        prefs[machines[m].name] = int(
-                            rng.integers(20, 200)
-                        )
-                    new_tasks.append(
-                        Task(
-                            uid=uid, job=job,
-                            cpu_request=float(
-                                rng.choice([0.1, 0.25, 0.5, 1.0])
-                            ),
-                            memory_request_kb=int(
-                                rng.choice([1, 2, 8])
-                            ) << 18,
-                            data_prefs=prefs,
-                        )
-                    )
-                n_arrive -= job_size
-            # a fraction of running work finishes
-            n_done = int(len(running) * finish_fraction)
-            done = [
-                running.pop(int(rng.integers(0, len(running))))
-                for _ in range(n_done)
-            ]
-            running.extend(t.uid for t in new_tasks)
-            yield new_tasks, done
-
-    return machines, rounds()

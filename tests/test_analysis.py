@@ -984,7 +984,7 @@ class TestWidenedTargets:
             p.relative_to(REPO).as_posix() for p in default_targets(REPO)
         }
         assert "tests/test_analysis.py" in rels
-        assert "bench.py" in rels
+        assert "scripts/adversarial_sweep.py" in rels
 
     def test_jit_hygiene_applies_in_tests_dir(self, tmp_path):
         vs = run_on(tmp_path, {"tests/test_x.py": """\

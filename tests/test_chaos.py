@@ -318,6 +318,16 @@ class TestOutbox:
             assert counts["replayed"] == 6
             assert outbox.pending == 0
 
+    def test_empty_pump_never_touches_the_wire(self):
+        # the pump runs every tick: with nothing parked it must cost
+        # no apiserver request at all
+        class _DeadClient:
+            def __getattr__(self, name):
+                raise AssertionError(f"empty pump called client.{name}")
+
+        counts = ActuationOutbox(_DeadClient()).pump()
+        assert not any(counts.values())
+
     def test_dead_letter_on_rejection(self):
         with FakeApiServer() as server:
             server.add_node("n0")
@@ -541,6 +551,39 @@ class TestScenarios:
         # the burst was absorbed by the tick path in ONE solve round
         placed = max(r.get("pods_placed", 0) for r in run.stats)
         assert placed >= 150
+
+    @pytest.mark.parametrize("name", [
+        "node_storm", "apiserver_outage", "overload_burst",
+    ])
+    def test_rerun_compiles_nothing(self, tmp_path, name):
+        """A second run of a seeded scenario reproduces the first's
+        shapes exactly, so recovery rides the warm compiled chain: the
+        rerun holds the invariants again with zero compiles."""
+        from poseidon_tpu.guards import CompileCounter
+
+        make, checks = {
+            "node_storm": (
+                scenario_node_storm,
+                dict(expect_guard=True, guard_release_rounds=5),
+            ),
+            "apiserver_outage": (scenario_apiserver_outage, {}),
+            "overload_burst": (scenario_overload_burst, {}),
+        }[name]
+        check_invariants(
+            run_daemon_scenario(make(), str(tmp_path), polling_ms=25.0),
+            **checks,
+        ).assert_ok()
+        counter = CompileCounter()
+        with counter:
+            run = run_daemon_scenario(
+                make(), str(tmp_path), polling_ms=25.0
+            )
+        check_invariants(run, **checks).assert_ok()
+        if not counter.supported:
+            pytest.skip("jax.monitoring not available")
+        assert counter.count == 0, (
+            f"{counter.count} recompile(s) rerunning {name}"
+        )
 
 
 @pytest.mark.slow

@@ -6,7 +6,7 @@ caught; these tests prove the contracts hold at runtime:
 - the resident round executes under ``jax.transfer_guard("disallow")``
   and performs EXACTLY ONE sanctioned placement fetch;
 - steady-state churned rounds stay at the recorded compile budget of
-  ZERO (a recompile regression fails tier-1, not just bench);
+  ZERO (a recompile regression fails tier-1);
 - the pipelined round's background fetch has a deadline
   (``--max_solver_runtime``) that degrades loudly — FetchTimeout +
   FETCH_TIMEOUT trace event + ``SchedulerStats.fetch_timeouts`` —

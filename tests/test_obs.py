@@ -13,10 +13,14 @@ import pytest
 from poseidon_tpu.bridge import SchedulerBridge, SchedulerStats
 from poseidon_tpu.cluster import Task
 from poseidon_tpu.obs import (
+    FlightRecorder,
     HealthState,
+    LifecycleTracker,
     MetricsRegistry,
     ObsServer,
     SchedulerMetrics,
+    ShadowAuditor,
+    SloEngine,
 )
 from poseidon_tpu.obs.metrics import (
     STORM_RESYNCS,
@@ -487,15 +491,49 @@ class TestTenantReport:
 
 
 class TestZeroRecompileUnderDrain:
-    def test_draining_pool_stays_zero_recompile(self):
-        """Regression for the three recompile sources bench config 10
-        flushed out: a pending pool that DRAINS across padding-bucket
+    @pytest.mark.parametrize("surface", [
+        "none", "metrics", "flight_recorder", "checkpoint", "observatory",
+    ])
+    def test_draining_pool_stays_zero_recompile(self, surface, tmp_path):
+        """Regression for the three recompile sources a draining pool
+        once exposed: a pending pool that DRAINS across padding-bucket
         boundaries (cost-input shapes), packs its free seats (the
         ``smax`` static), and narrows its pref width (the ``n_prefs``
         static) must stay at zero steady-state recompiles — the
         solver's grow-only floors now cover all three axes, not just
-        the topology padding."""
+        the topology padding.
+
+        Every host-side surface the daemon can attach to a round reads
+        host values only, so the budget holds with it on: the metrics
+        + trace ring, the flight recorder capturing every round, a
+        checkpoint captured every round (and the warm restore's first
+        round: a delta build whose restored floors reproduce the
+        compiled shapes), and the observatory (per-pod lifecycle, the
+        shadow audit sampled every other round, the SLO engine)."""
         from poseidon_tpu.guards import CompileCounter
+        from poseidon_tpu.ha import restore_bridge
+        from poseidon_tpu.ha.checkpoint import capture_snapshot
+
+        kw: dict = {}
+        slo = auditor = None
+        if surface == "metrics":
+            kw = dict(metrics=SchedulerMetrics(MetricsRegistry()),
+                      trace=TraceGenerator())
+        elif surface == "flight_recorder":
+            kw = dict(flightrec=FlightRecorder(str(tmp_path), rounds=4))
+        elif surface == "observatory":
+            metrics = SchedulerMetrics(MetricsRegistry())
+            trace = TraceGenerator()
+            auditor = ShadowAuditor(
+                metrics=metrics, sample_every=2, background=False
+            )
+            auditor.prewarm(tasks=224, machines=16)
+            kw = dict(metrics=metrics, trace=trace, auditor=auditor,
+                      lifecycle=LifecycleTracker(metrics))
+            slo = SloEngine(
+                ["e2c_p99_ms < 60000 by lane=tick", "regret == 0"],
+                metrics=metrics, trace=trace,
+            )
 
         # oversubscribed on purpose: 160 seats, 224 pods — a standing
         # unscheduled pool of ~64 that the churn below drains a few
@@ -506,7 +544,7 @@ class TestZeroRecompileUnderDrain:
             16, 224, seed=7, prefs_per_task=2
         )
         bridge = SchedulerBridge(
-            cost_model="quincy", small_to_oracle=False,
+            cost_model="quincy", small_to_oracle=False, **kw
         )
         bridge.observe_nodes(list(cluster.machines))
         bridge.observe_pods(list(cluster.tasks))
@@ -514,6 +552,7 @@ class TestZeroRecompileUnderDrain:
         for uid, m in res.bindings.items():
             bridge.confirm_binding(uid, m)
         running = list(res.bindings)
+        snaps = []
         seq = 0
 
         def churn_round():
@@ -540,18 +579,42 @@ class TestZeroRecompileUnderDrain:
             for uid, m in r.bindings.items():
                 bridge.confirm_binding(uid, m)
                 running.append(uid)
+            if surface == "checkpoint":
+                snaps.append(capture_snapshot(bridge))
+            if auditor is not None:
+                auditor.run_pending()
+            if slo is not None:
+                slo.evaluate(r.stats.round_num)
 
-        for _ in range(2):  # warm both chain variants
+        for _ in range(2):  # warm both chain variants (and the audit)
             churn_round()
         counter = CompileCounter()
         with counter:
             for _ in range(10):
                 churn_round()
+        if surface == "checkpoint":
+            # the restore rebuilds host state outside the count; the
+            # restored bridge's first round is what must reuse the
+            # compiled shapes
+            warm = SchedulerBridge(
+                cost_model="quincy", small_to_oracle=False
+            )
+            restore_bridge(warm, snaps[-1])
+            with counter:
+                warm.observe_pod_event("ADDED", Task(
+                    uid="restored-arrival", cpu_request=0.1,
+                    memory_request_kb=64,
+                ))
+                r = warm.run_scheduler()
+            assert r.stats.build_mode == "delta"
+            assert r.stats.backend == "dense_auction"
         if not counter.supported:
             pytest.skip("jax.monitoring not available")
+        if auditor is not None:
+            assert auditor.completed >= 5 and not auditor.failures
         assert counter.count == 0, (
             f"{counter.count} recompile(s) during a draining-pool "
-            f"steady state"
+            f"steady state with the {surface} surface on"
         )
 
 

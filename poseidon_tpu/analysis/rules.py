@@ -601,7 +601,7 @@ def surface_consistency(repo: RepoContext) -> list[Violation]:
             for node in ast.walk(fctx.tree):
                 if not (isinstance(node, ast.Call)
                         and isinstance(node.func, ast.Attribute)
-                        and node.func.attr == "emit"):
+                        and node.func.attr in ("emit", "emit_many")):
                     continue
                 base = node.func.value
                 base_name = (

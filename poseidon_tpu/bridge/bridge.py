@@ -172,7 +172,10 @@ class SchedulerStats:
     solved it. ``observe_listed`` counts the nodes and pods the poll
     snapshots (``observe_nodes`` / ``observe_pods``) handed over since
     the previous round, and ``observe_elided`` those of them whose
-    upsert was elided as unchanged.
+    upsert was elided as unchanged. ``records_batched`` counts the
+    per-pod records written in batches: the SUBMIT events of the pods
+    observed new since the previous round began, plus this round's
+    SCHEDULE events.
     """
 
     round_num: int = 0
@@ -261,6 +264,7 @@ class SchedulerStats:
     auction_rounds: int = 0
     observe_listed: int = 0
     observe_elided: int = 0
+    records_batched: int = 0
 
 
 @dataclasses.dataclass
@@ -435,6 +439,10 @@ class SchedulerBridge:
         self._pod_seen: dict[str, tuple] = {}
         self._observe_listed = 0
         self._observe_elided = 0
+        # the per-pod records (SUBMIT + SCHEDULE) written as batches
+        # since the last round began: the observes' SUBMITs here, the
+        # round's own SCHEDULEs added by its apply
+        self._records_batched = 0
         # accumulators the cli loop feeds (its poseidon/list and
         # poseidon/post_bindings spans add here; SchedulerStats
         # documents the windows): LIST ms, moved into stats at begin;
@@ -746,19 +754,21 @@ class SchedulerBridge:
               or known.memory_request_kb != pod.memory_request_kb):
             g.note_task_updated(stored)
 
-    def _upsert_pod(self, pod: Task) -> TaskSample | None:
+    def _upsert_pod(
+        self, pod: Task, submits: list[str]
+    ) -> TaskSample | None:
         """One pod's state-machine dispatch (the reference's per-pod
         switch, scheduler_bridge.cc:132-162). Shared by the poll
         snapshot diff and the watch event path so both drive identical
-        transitions and churn notes. Returns the knowledge sample it
-        pushed (a pod adopted Running), else None."""
+        transitions and churn notes. A new pending pod's uid is
+        appended to ``submits``, which the caller hands to
+        ``_record_submits``. Returns the knowledge sample it pushed (a
+        pod adopted Running), else None."""
         g = self._graph
         known = self.tasks.get(pod.uid)
         if pod.phase == TaskPhase.PENDING:
             if known is None:
-                log.info("new pending pod %s", pod.uid)
-                self.trace.emit("SUBMIT", task=pod.uid,
-                                round_num=self.round_num)
+                submits.append(pod.uid)
                 if self.lifecycle is not None:
                     # timeline zero: first sight of schedulable work
                     # (the express path backdates to the watch
@@ -881,6 +891,21 @@ class SchedulerBridge:
                     self.lifecycle.drop(pod.uid)
         return None
 
+    def _record_submits(self, uids: list[str]) -> None:
+        """An observe's new pending pods: their SUBMIT events as one
+        trace batch and their "new pending pod" lines as one INFO
+        record, a line a pod."""
+        if not uids:
+            return
+        self._records_batched += len(uids)
+        self.trace.emit_many(
+            "SUBMIT", [(uid, "", None) for uid in uids], self.round_num
+        )
+        if log.isEnabledFor(logging.INFO):
+            log.info("%s", "\n".join(
+                [f"new pending pod {uid}" for uid in uids]
+            ))
+
     def _remove_pod(self, uid: str) -> None:
         """A pod left the cluster without a terminal phase (poll: gone
         from the snapshot; watch: an explicit DELETED event): retire it
@@ -932,23 +957,27 @@ class SchedulerBridge:
             elided = 0
             uids: list[str] = []
             samples: list[TaskSample] = []
-            for pod in pods:
-                uid = pod.uid
-                seen.add(uid)
-                rec = last.get(uid)
-                if (rec is not None and rec[0] is pod
-                        and tasks.get(uid) is rec[1]):
-                    record[uid] = rec
-                    elided += 1
-                    if rec[2] is not None:
-                        uids.append(uid)
-                        samples.append(rec[2])
-                    continue
-                sample = self._upsert_pod(pod)
-                stored = tasks.get(uid)
-                if (pod.phase != TaskPhase.RUNNING or stored is None
-                        or stored.phase == TaskPhase.RUNNING):
-                    record[uid] = (pod, stored, sample)
+            submits: list[str] = []
+            try:
+                for pod in pods:
+                    uid = pod.uid
+                    seen.add(uid)
+                    rec = last.get(uid)
+                    if (rec is not None and rec[0] is pod
+                            and tasks.get(uid) is rec[1]):
+                        record[uid] = rec
+                        elided += 1
+                        if rec[2] is not None:
+                            uids.append(uid)
+                            samples.append(rec[2])
+                        continue
+                    sample = self._upsert_pod(pod, submits)
+                    stored = tasks.get(uid)
+                    if (pod.phase != TaskPhase.RUNNING or stored is None
+                            or stored.phase == TaskPhase.RUNNING):
+                        record[uid] = (pod, stored, sample)
+            finally:
+                self._record_submits(submits)
             self._pod_seen = record
             if uids:
                 self.knowledge.add_task_samples(uids, samples)
@@ -974,7 +1003,9 @@ class SchedulerBridge:
             if type_ == "DELETED":
                 self._remove_pod(pod.uid)
             else:
-                self._upsert_pod(pod)
+                submits: list[str] = []
+                self._upsert_pod(pod, submits)
+                self._record_submits(submits)
 
     def note_watch_activity(
         self, resyncs: int = 0, reconnects: int = 0
@@ -1581,6 +1612,8 @@ class SchedulerBridge:
         stats.observe_listed = self._observe_listed
         stats.observe_elided = self._observe_elided
         self._observe_listed = self._observe_elided = 0
+        stats.records_batched = self._records_batched
+        self._records_batched = 0
         stats.list_ms = round(self.pending_list_ms, 3)
         self.pending_list_ms = 0.0
         stats.watch_resyncs = self._watch_resyncs
@@ -1935,7 +1968,8 @@ class SchedulerBridge:
     def _apply(self, ir: InflightRound, outcome):
         """The apply phase (``poseidon/apply``, ``finish_ms``): typed
         deltas from the solved assignment, then per pod its decision,
-        aging, trace event, log line and lifecycle stamp. Returns
+        aging and lifecycle stamp; the placements' SCHEDULE events and
+        PLACE lines go out as one batch each. Returns
         ``(bindings, unscheduled, unsched_ages, migrations,
         preemptions, dset)``."""
         stats = ir.stats
@@ -1992,6 +2026,7 @@ class SchedulerBridge:
                 return None
             return task
 
+        schedules: list[tuple[str, str, dict]] = []
         for d in dset.place:
             task = _live_pending(d.task)
             if task is None:
@@ -2014,14 +2049,18 @@ class SchedulerBridge:
                 {"machine": d.machine, "cost": d.cost,
                  "margin": d.margin},
             ))
-            self.trace.emit("SCHEDULE", task=d.task, machine=d.machine,
-                            round_num=ir.stats.round_num,
-                            detail={"cost": d.cost,
-                                    "margin": d.margin})
-            log.info(
-                "round %d: PLACE %s -> %s",
-                ir.stats.round_num, d.task, d.machine,
-            )
+            schedules.append((d.task, d.machine,
+                              {"cost": d.cost, "margin": d.margin}))
+        # one trace batch and one INFO record (a line a placement) for
+        # the round's PLACEs, before its MIGRATE/PREEMPT/ROUND records
+        stats.records_batched += len(schedules)
+        self.trace.emit_many("SCHEDULE", schedules, stats.round_num)
+        if schedules and log.isEnabledFor(logging.INFO):
+            rn = stats.round_num
+            log.info("%s", "\n".join([
+                f"round {rn}: PLACE {task} -> {machine}"
+                for task, machine, _ in schedules
+            ]))
         for uid in dset.unscheduled:
             task = _live_pending(uid)
             if task is not None:

@@ -62,6 +62,15 @@ ROUND record at finish time, so a round's SCHEDULE/ROUND events may
 interleave with the NEXT round's SUBMIT events in the stream;
 ``read_trace`` does the ``round_num`` ordering for consumers.
 
+Per-pod records are batched: ``emit_many(event, rows, round_num)``
+writes one line per ``(task, machine, detail)`` row, each stamped by
+its own ``clock_us()`` call, in ONE sink write; ``emit`` is a batch of
+one, so a batch writes the lines its rows would one by one. The bridge
+writes a round's SCHEDULE events this way where its PLACE loop ends,
+and an observe's SUBMIT events where the observe ends, so a SUBMIT may
+follow a FINISH that ``emit`` wrote inside the same observe; each
+event type keeps its own order.
+
 Command line: ``python -m poseidon_tpu.trace report <file>`` renders
 the operator's one-pager (round-latency percentiles by lane/build
 mode, express event-to-bind percentiles, degrade/resync/timeout
@@ -83,9 +92,10 @@ log = logging.getLogger(__name__)
 # The DECLARED event vocabulary. Consumers key on these names, so an
 # emit outside the set is a silent contract break for every downstream
 # trace reader: the static pass (analysis/rules.py PTA005) checks every
-# ``*.emit("NAME")`` call site against this set, and ``emit`` enforces
-# it at runtime. Extending the vocabulary = adding the name here (and
-# documenting it in the module docstring above).
+# ``*.emit("NAME")`` and ``*.emit_many("NAME", ...)`` call site against
+# this set, and ``emit_many`` enforces it at runtime. Extending the
+# vocabulary = adding the name here (and documenting it in the module
+# docstring above).
 EVENT_TYPES = frozenset({
     "SUBMIT",           # pod observed
     "SCHEDULE",         # placement decision
@@ -201,37 +211,55 @@ class TraceGenerator:
         round_num: int = 0,
         detail: dict | None = None,
     ) -> None:
+        self.emit_many(event, [(task, machine, detail)], round_num)
+
+    def emit_many(
+        self,
+        event: str,
+        rows: list[tuple[str, str, dict | None]],
+        round_num: int,
+    ) -> None:
+        """One event per ``(task, machine, detail)`` row, in row order,
+        each stamped by its own ``clock_us()`` call, written to the sink
+        in ONE write (or appended to the ring). A round's SCHEDULE and
+        SUBMIT records come this way, one batch per phase; ``emit`` is
+        a batch of one."""
         if event not in EVENT_TYPES:
             raise ValueError(
                 f"undeclared trace event {event!r}; the vocabulary is "
                 f"trace.EVENT_TYPES (PTA005)"
             )
-        ev = TraceEvent(
-            timestamp_us=self.clock_us(),
-            event=event,
-            task=task,
-            machine=machine,
-            round_num=round_num,
-            detail=detail,
-            tenant=self.tenant,
-        )
+        if not rows:
+            return
+        clock, tenant = self.clock_us, self.tenant
         if self.sink is not None:
-            self.sink.write(json.dumps(dataclasses.asdict(ev)) + "\n")
-        else:
-            if (
-                self.events.maxlen is not None
-                and len(self.events) == self.events.maxlen
-            ):
-                if not self.dropped_total:
-                    log.warning(
-                        "trace ring full (%d events, no sink): "
-                        "overwriting oldest — this in-memory trace is "
-                        "now PARTIAL (counted in dropped_total / "
-                        "poseidon_trace_dropped_total)",
-                        self.events.maxlen,
-                    )
-                self.dropped_total += 1
-            self.events.append(ev)
+            dumps = json.dumps
+            # a line is a TraceEvent's fields in declaration order
+            self.sink.write("".join([
+                dumps({"timestamp_us": clock(), "event": event,
+                       "task": task, "machine": machine,
+                       "round_num": round_num, "detail": detail,
+                       "tenant": tenant}) + "\n"
+                for task, machine, detail in rows
+            ]))
+            return
+        maxlen = self.events.maxlen
+        over = 0 if maxlen is None else len(self.events) + len(rows) - maxlen
+        if over > 0:
+            if not self.dropped_total:
+                log.warning(
+                    "trace ring full (%d events, no sink): overwriting "
+                    "oldest — this in-memory trace is now PARTIAL "
+                    "(counted in dropped_total / "
+                    "poseidon_trace_dropped_total)",
+                    maxlen,
+                )
+            self.dropped_total += over
+        self.events.extend(
+            TraceEvent(clock(), event, task, machine, round_num, detail,
+                       tenant)
+            for task, machine, detail in rows
+        )
 
     def flush(self) -> None:
         if self.sink is not None:

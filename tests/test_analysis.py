@@ -260,6 +260,23 @@ class TestPTA005Surface:
         msgs = " | ".join(v.message for v in vs)
         assert "BOGUS" in msgs and "dynamic" in msgs
 
+    def test_undeclared_batch_event_flagged(self, tmp_path):
+        vs = run_on(tmp_path, {
+            "poseidon_tpu/trace.py": """\
+                EVENT_TYPES = frozenset({"SUBMIT", "SCHEDULE"})
+            """,
+            "poseidon_tpu/other.py": """\
+                class T:
+                    def go(self, rows, name):
+                        self.trace.emit_many("SCHEDULE", rows, 1)
+                        self.trace.emit_many("PLACED", rows, 1)
+                        self.trace.emit_many(name, rows, 1)
+            """,
+        })
+        assert codes(vs) == ["PTA005", "PTA005"]
+        msgs = " | ".join(v.message for v in vs)
+        assert "PLACED" in msgs and "dynamic" in msgs
+
     def test_missing_vocab_flagged(self, tmp_path):
         vs = run_on(tmp_path, {"poseidon_tpu/trace.py": """\
             def emit(x):

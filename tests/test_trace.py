@@ -2,7 +2,10 @@
 forward compatibility, ring-buffer semantics, and the PTA005 runtime
 vocabulary guard."""
 
+import dataclasses
+import io
 import json
+import logging
 
 import pytest
 
@@ -202,3 +205,284 @@ class TestVocabularyGuard:
                 assert e.detail["from"] and e.machine
             if e.event == "PREEMPT":
                 assert e.machine
+
+
+def _fields(line: str) -> dict:
+    """A trace line's fields but the wall-clock stamp."""
+    doc = json.loads(line)
+    del doc["timestamp_us"]
+    return doc
+
+
+ROWS = [
+    ("default/p0", "m0", {"cost": 12, "margin": 3}),
+    ("default/p1", "m1", {"cost": 7, "margin": 0.5}),
+    ("ns/pé-\"2\"", "", None),
+]
+
+
+def _asdict_line(ts, event, task, machine, round_num, detail, tenant=""):
+    """A line as the per-event writer serialised it: the dataclass
+    through ``dataclasses.asdict``."""
+    return json.dumps(dataclasses.asdict(TraceEvent(
+        ts, event, task, machine, round_num, detail, tenant))) + "\n"
+
+
+class TestEmitMany:
+    """``emit_many`` writes, in one sink write, the lines the per-event
+    writer wrote one event at a time; ``emit`` is a batch of one."""
+
+    @pytest.mark.parametrize("tenant", ["", "acme"])
+    @pytest.mark.parametrize("batched", [True, False])
+    def test_lines_equal_the_per_event_format(self, tmp_path, tenant,
+                                              batched):
+        path = tmp_path / "t.jsonl"
+        with open(path, "w") as fh:
+            clock = iter(range(100, 200))
+            gen = TraceGenerator(sink=fh, clock_us=lambda: next(clock),
+                                 tenant=tenant)
+            if batched:
+                gen.emit_many("SCHEDULE", ROWS, 4)
+            else:
+                for task, machine, detail in ROWS:
+                    gen.emit("SCHEDULE", task=task, machine=machine,
+                             round_num=4, detail=detail)
+        # same clock readings: byte-identical, key order included
+        assert path.read_text() == "".join(
+            _asdict_line(100 + i, "SCHEDULE", task, machine, 4, detail,
+                         tenant)
+            for i, (task, machine, detail) in enumerate(ROWS)
+        )
+
+    def test_one_sink_write_and_a_stamp_per_event(self):
+        writes: list[str] = []
+
+        class Sink:
+            def write(self, text):
+                writes.append(text)
+
+        stamps = iter(range(10))
+        gen = TraceGenerator(sink=Sink(), clock_us=lambda: next(stamps))
+        gen.emit_many("SUBMIT", ROWS, 2)
+        assert len(writes) == 1
+        lines = writes[0].splitlines()
+        assert [json.loads(x)["timestamp_us"] for x in lines] == [0, 1, 2]
+        assert writes[0].endswith("\n")
+        gen.emit_many("SUBMIT", [], 2)  # an empty batch writes nothing
+        assert len(writes) == 1
+
+    def test_sinkless_batch_fills_the_ring_with_events(self):
+        stamps = iter(range(10))
+        gen = TraceGenerator(clock_us=lambda: next(stamps), tenant="t")
+        gen.emit_many("SCHEDULE", ROWS, 1)
+        assert list(gen.events) == [
+            TraceEvent(i, "SCHEDULE", task, machine, 1, detail, "t")
+            for i, (task, machine, detail) in enumerate(ROWS)
+        ]
+
+    @pytest.mark.parametrize("before,batch", [(3, 4), (0, 7), (5, 1), (1, 1)])
+    def test_ring_overflow_counted_exactly(self, caplog, before, batch):
+        """The sinkless ring keeps its maxlen and counts every event a
+        batch pushes out, warning once."""
+        gen = TraceGenerator(buffer_events=5)
+        for i in range(before):
+            gen.emit("SUBMIT", task=f"p{i}")
+        rows = [(f"q{i}", "", None) for i in range(batch)]
+        with caplog.at_level("WARNING", logger="poseidon_tpu.trace"):
+            gen.emit_many("SUBMIT", rows, 0)
+            gen.emit_many("SUBMIT", rows, 0)
+        tasks = [f"p{i}" for i in range(before)] + [t for t, _, _ in rows] * 2
+        assert gen.events.maxlen == 5
+        assert [e.task for e in gen.events] == tasks[-5:]
+        assert gen.dropped_total == max(0, len(tasks) - 5)
+        warned = [m for m in caplog.messages if "ring full" in m]
+        assert len(warned) == (1 if gen.dropped_total else 0)
+
+    @pytest.mark.parametrize("rows", [ROWS, []])
+    def test_undeclared_event_in_a_batch_raises(self, rows):
+        gen = TraceGenerator()
+        with pytest.raises(ValueError, match="PTA005"):
+            gen.emit_many("REBALANCE", rows, 1)
+        assert len(gen.events) == 0
+
+    def test_report_over_batched_equals_per_event(self, tmp_path, capsys):
+        """``python -m poseidon_tpu.trace report`` and obs/report.py's
+        churn totals read a batched file as they read a per-event one."""
+        import dataclasses as dc
+
+        from poseidon_tpu.bridge import SchedulerStats
+        from poseidon_tpu.obs.report import analyze_trace
+        from poseidon_tpu.trace import main
+
+        paths = []
+        for batched in (False, True):
+            path = tmp_path / f"t{int(batched)}.jsonl"
+            with open(path, "w") as fh:
+                gen = TraceGenerator(sink=fh)
+                for rn in (1, 2):
+                    submits = [(f"p{rn}-{i}", "", None) for i in range(4)]
+                    places = [(f"p{rn}-{i}", f"m{i}", {"cost": i,
+                                                        "margin": 1})
+                              for i in range(3)]
+                    if batched:
+                        gen.emit_many("SUBMIT", submits, rn - 1)
+                        gen.emit("FINISH", task="old", round_num=rn - 1)
+                        gen.emit_many("SCHEDULE", places, rn)
+                    else:
+                        gen.emit("FINISH", task="old", round_num=rn - 1)
+                        for task, _, _ in submits:
+                            gen.emit("SUBMIT", task=task,
+                                     round_num=rn - 1)
+                        for task, machine, detail in places:
+                            gen.emit("SCHEDULE", task=task,
+                                     machine=machine, round_num=rn,
+                                     detail=detail)
+                    stats = SchedulerStats(round_num=rn, pods_placed=3,
+                                           total_ms=1.0 + rn)
+                    gen.emit("ROUND", round_num=rn, detail=dc.asdict(stats))
+            paths.append(str(path))
+        reports = [analyze_trace(p) for p in paths]
+        assert reports[0] == reports[1]
+        assert reports[0]["churn"]["totals"]["SCHEDULE"] == 6
+        assert reports[0]["churn"]["totals"]["SUBMIT"] == 8
+        outs = []
+        for p in paths:
+            assert main(["report", p, "--json"]) == 0
+            outs.append(capsys.readouterr().out)
+        assert outs[0] == outs[1]
+
+
+def _bridge_round(n_pods=9, n_running=2):
+    """A bridge with a sink: nodes, a running pod or two, and
+    ``n_pods`` new pending pods; returns the bridge, the sink, the
+    pending uids in observe order and the round's result."""
+    from poseidon_tpu.bridge import SchedulerBridge
+    from poseidon_tpu.cluster import Machine, Task, TaskPhase
+
+    sink = io.StringIO()
+    bridge = SchedulerBridge(cost_model="quincy",
+                             trace=TraceGenerator(sink=sink))
+    bridge.observe_nodes([Machine(name=f"m{i}", max_tasks=4)
+                          for i in range(4)])
+    pending = [f"ns/p{i}" for i in range(n_pods)]
+    bridge.observe_pods(
+        [Task(uid=f"ns/r{i}", phase=TaskPhase.RUNNING, machine="m0")
+         for i in range(n_running)]
+        + [Task(uid=uid, phase=TaskPhase.PENDING) for uid in pending]
+    )
+    result = bridge.run_scheduler()
+    return bridge, sink, pending, result
+
+
+class TestBridgeRecords:
+    """A round's SUBMIT and SCHEDULE records and its PLACE / new-pod
+    log lines, written one batch per phase."""
+
+    def test_round_trace_equals_per_event_path(self):
+        bridge, sink, pending, result = _bridge_round()
+        assert result.stats.pods_placed == len(pending)
+        got = [_fields(x) for x in sink.getvalue().splitlines()]
+        # the per-event path's lines, from what the bridge decided
+        want = [_fields(_asdict_line(0, "SUBMIT", uid, "", 0, None))
+                for uid in pending]
+        for rn, kind, uid, d in bridge.decision_log:
+            assert kind == "PLACE"
+            want.append(_fields(_asdict_line(
+                0, "SCHEDULE", uid, d["machine"], rn,
+                {"cost": d["cost"], "margin": d["margin"]})))
+        for kind in ("SUBMIT", "SCHEDULE"):
+            assert [e for e in got if e["event"] == kind] == [
+                e for e in want if e["event"] == kind
+            ]
+        # the apply's batch lands before the round's ROUND record
+        kinds = [e["event"] for e in got]
+        assert kinds == ["SUBMIT"] * 9 + ["SCHEDULE"] * 9 + ["ROUND"]
+        assert {e["task"] for e in got if e["event"] == "SCHEDULE"} == set(
+            result.bindings)
+
+    def test_watch_event_writes_a_batch_of_one(self):
+        from poseidon_tpu.bridge import SchedulerBridge
+        from poseidon_tpu.cluster import Machine, Task, TaskPhase
+
+        bridge = SchedulerBridge(cost_model="quincy")
+        bridge.observe_nodes([Machine(name="m0", max_tasks=2)])
+        bridge.observe_pod_event(
+            "ADDED", Task(uid="w0", phase=TaskPhase.PENDING))
+        bridge.observe_pod_event(
+            "MODIFIED", Task(uid="w0", phase=TaskPhase.PENDING))
+        assert [(e.event, e.task) for e in bridge.trace.events] == [
+            ("SUBMIT", "w0")]
+
+    def test_records_batched_counts_submit_and_schedule(self):
+        bridge, sink, pending, result = _bridge_round()
+        events = [json.loads(x)["event"] for x in
+                  sink.getvalue().splitlines()]
+        assert result.stats.records_batched == (
+            events.count("SUBMIT") + events.count("SCHEDULE")) == 18
+        # nothing new, nothing placed: the counter reads 0
+        for uid, machine in result.bindings.items():
+            bridge.confirm_binding(uid, machine)
+        again = bridge.run_scheduler()
+        assert again.stats.pods_placed == 0
+        assert again.stats.records_batched == 0
+
+    def test_info_log_carries_every_line_in_one_record_per_phase(
+        self, caplog
+    ):
+        with caplog.at_level(logging.INFO, logger="poseidon_tpu.bridge"):
+            bridge, _, pending, result = _bridge_round()
+        lines = "\n".join(caplog.messages).splitlines()
+        for uid in pending:
+            assert f"new pending pod {uid}" in lines
+        rn = result.stats.round_num
+        for uid, machine in result.bindings.items():
+            assert f"round {rn}: PLACE {uid} -> {machine}" in lines
+        per_pod = [r for r in caplog.records
+                   if "new pending pod" in r.getMessage()
+                   or ": PLACE " in r.getMessage()]
+        # one record per phase, a line per pod in each
+        assert len(per_pod) == 2
+        assert [len(r.getMessage().splitlines()) for r in per_pod] == [
+            len(pending), len(result.bindings)]
+
+    def test_no_per_pod_line_formatted_below_info(self):
+        """With INFO off, the uids are never formatted into lines."""
+        from poseidon_tpu.bridge import SchedulerBridge
+        from poseidon_tpu.cluster import Machine, Task, TaskPhase
+
+        formatted = []
+
+        class Uid(str):
+            def __format__(self, spec):
+                formatted.append(str(self))
+                return str.__format__(self, spec)
+
+        logger = logging.getLogger("poseidon_tpu.bridge.bridge")
+        seen = []
+
+        class Count(logging.Handler):
+            def emit(self, record):
+                seen.append(record)
+
+        handler = Count(level=logging.DEBUG)
+        logger.addHandler(handler)
+        old = logger.level
+        try:
+            bridge = SchedulerBridge(cost_model="quincy")
+            bridge.observe_nodes([Machine(name="m0", max_tasks=8)])
+            for level in (logging.WARNING, logging.INFO):
+                logger.setLevel(level)
+                uids = [Uid(f"ns/{level}-{i}") for i in range(3)]
+                bridge.observe_pods(
+                    [Task(uid=u, phase=TaskPhase.PENDING) for u in uids])
+                result = bridge.run_scheduler()
+                assert len(result.bindings) == 3
+                if level == logging.WARNING:
+                    assert formatted == [] and seen == []
+            # at INFO both phases format their pods' lines
+            assert sorted(set(formatted)) == sorted(
+                f"ns/{logging.INFO}-{i}" for i in range(3))
+            assert len(seen) == 2
+        finally:
+            logger.removeHandler(handler)
+            logger.setLevel(old)

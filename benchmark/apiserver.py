@@ -15,7 +15,9 @@ GET and evictions), with three changes for measuring:
   stream sends those bytes;
 - the server stamps each pod's due time and the arrival of its binding
   on this process's ``time.monotonic()`` clock, and counts bindings that
-  land on a node already at its pod capacity;
+  land on a node already at its pod capacity, and bindings that land on
+  a node the pod's placement constraints exclude (``constraints.py``;
+  like a real apiserver, it accepts both);
 - it records, per pod, the resourceVersions at which it was added, was
   seen bound and was deleted, and, per pod LIST, the resourceVersion its
   snapshot holds: the reference rebuilds from them what each round saw;
@@ -56,6 +58,7 @@ from urllib.parse import parse_qs, urlparse
 import numpy as np
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
+import constraints  # noqa: E402
 import traffic_gen  # noqa: E402
 
 EVENT_RETENTION = 400_000   # watch history kept (a real apiserver keeps
@@ -91,6 +94,7 @@ class Pod:
         if spec.prefs:
             meta["annotations"] = {
                 "poseidon.io/data-prefs": json.dumps(spec.prefs)}
+        con = spec.constraint or {}
         self.doc = {
             "metadata": meta,
             "spec": {
@@ -98,6 +102,12 @@ class Pod:
                     "cpu": f"{spec.cpu_milli}m",
                     "memory": f"{spec.memory_kb}Ki",
                 }}}],
+                **({"nodeSelector": con["node_selector"]}
+                   if "node_selector" in con else {}),
+                **({"affinity": {"nodeAffinity": con["affinity"]}}
+                   if "affinity" in con else {}),
+                **({"tolerations": con["tolerations"]}
+                   if "tolerations" in con else {}),
                 **({"nodeName": node} if node else {}),
             },
             "status": {"phase": "Running" if node else "Pending"},
@@ -116,7 +126,7 @@ class Cluster:
         self.seed, self.seconds = seed, seconds
         self.kind = traffic["kind"]
         self.nodes = traffic_gen.make_nodes(cfg, seed)
-        self.node_cap = {n.name: n.pods for n in self.nodes}
+        self.node_of = {n.name: n for n in self.nodes}
         self.node_raw = {n.name: _dumps(self._node_doc(n))
                          for n in self.nodes}
         self.live_bound = collections.Counter()
@@ -132,6 +142,7 @@ class Cluster:
         self.closing = False
         # counters for the record
         self.overcommit = 0
+        self.constraint_violations = 0
         self.conflicts = 0
         self.rejected = 0
         self.posts = 0
@@ -163,10 +174,10 @@ class Cluster:
 
     @staticmethod
     def _node_doc(n) -> dict:
-        labels = {"rack": n.rack} if n.rack else {}
         res = {"cpu": f"{n.cpu_milli}m", "memory": f"{n.memory_kb}Ki",
                "pods": str(n.pods)}
-        return {"metadata": {"name": n.name, "labels": labels},
+        return {"metadata": {"name": n.name, "labels": n.all_labels},
+                **({"spec": {"taints": list(n.taints)}} if n.taints else {}),
                 "status": {"capacity": res, "allocatable": dict(res)}}
 
     @contextlib.contextmanager
@@ -243,7 +254,8 @@ class Cluster:
         if pod is None:
             self.rejected += 1
             return 404, {"error": f"no pod {key}"}
-        if node not in self.node_cap:
+        n = self.node_of.get(node)
+        if n is None:
             self.rejected += 1
             return 404, {"error": f"no node {node}"}
         if pod.node:
@@ -252,8 +264,13 @@ class Cluster:
                          "reason": "Conflict",
                          "message": f"pod {key} is already bound to "
                                     f"{pod.node}"}
-        if self.live_bound[node] >= self.node_cap[node]:
+        if self.live_bound[node] >= n.pods:
             self.overcommit += 1
+        # an unconstrained pod fits an untainted node: no test on the POST
+        # path, which bounds how fast a wave binds
+        if (pod.spec.constraint or n.taints) and not constraints.feasible(
+                pod.spec.constraint, n.all_labels, n.taints):
+            self.constraint_violations += 1
         self.live_bound[node] += 1
         pod.node = node
         pod.bound = now
@@ -372,14 +389,20 @@ class Cluster:
         self.build_wave(w + 1)
 
     def build_wave(self, w: int) -> None:
-        """Build wave ``w``'s pods on a thread of their own."""
+        """Build wave ``w``'s pods on a thread of their own: one job, or
+        ``wave_jobs`` jobs of consecutive pods."""
         out: list[Pod] = []
-        job = f"wave-{w:04d}"
+        n = int(self.traffic["wave_pods"])
+        parts = int(self.traffic.get("wave_jobs", 1))
+
+        def job(k: int) -> str:
+            return f"wave-{w:04d}" if parts == 1 else \
+                f"wave-{w:04d}-j{k * parts // n:03d}"
 
         def build():
-            out.extend(Pod(self.wave_factory.make(f"{job}-{k:05d}", job),
-                           wave=w)
-                       for k in range(int(self.traffic["wave_pods"])))
+            out.extend(Pod(self.wave_factory.make(f"{job(k)}-{k:05d}",
+                                                  job(k)), wave=w)
+                       for k in range(n))
         th = threading.Thread(target=build, daemon=True)
         th.start()
         self.wave_builder = (w, th, out)
@@ -418,9 +441,13 @@ class Cluster:
         return True
 
     def warm_burst(self, n: int) -> None:
-        """``n`` pending pods at once, built first."""
-        built = [Pod(self.factory.make(f"warm-{k:05d}", "warm"))
-                 for k in range(n)]
+        """``n`` pending pods at once, built first: one job, or jobs of
+        ``warmup_job_pods`` pods (so a warm-up can carry several of the
+        configuration's constraints, which come one per job)."""
+        size = int(self.traffic.get("warmup_job_pods", 0))
+        built = [Pod(self.factory.make(
+            f"warm-{k:05d}", f"warm-{k // size:05d}" if size else "warm"))
+            for k in range(n)]
         with self.cond:
             now = time.monotonic()
             for pod in built:
@@ -509,7 +536,8 @@ class Cluster:
                 s = p.spec
                 pods.append([p.key, p.due, p.created, p.bound, p.node,
                              p.deleted, s.job, s.cpu_milli, s.memory_kb,
-                             s.prefs, p.rv_added, p.rv_bound, p.rv_deleted])
+                             s.prefs, p.rv_added, p.rv_bound, p.rv_deleted,
+                             s.constraint])
             late = np.asarray(self.lateness) if self.lateness else \
                 np.zeros(1)
             in_window = sum(1 for p in list(self.pods.values()) + self.gone
@@ -517,9 +545,11 @@ class Cluster:
                             and self.t0 <= p.bound < self.t1)
             return {
                 "t0": self.t0, "t1": self.t1, "report_t": now,
-                "nodes": [[n.name, n.rack, n.pods] for n in self.nodes],
+                "nodes": [[n.name, n.rack, n.pods, n.all_labels,
+                           list(n.taints)] for n in self.nodes],
                 "pods": pods,
                 "overcommit": self.overcommit,
+                "constraint_violations": self.constraint_violations,
                 "conflicts": self.conflicts,
                 "rejected": self.rejected,
                 "posts": self.posts,

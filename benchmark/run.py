@@ -651,6 +651,7 @@ def check(cell: Cell, rec: Recorder, report: dict
         "round_infeasible": (infeasible, 0),
         "observed_mismatch": (mismatch, 0),
         "apiserver_overcommit": (report["overcommit"], 0),
+        "constraint_violations": (report["constraint_violations"], 0),
         "actuation_mismatch": (actuation, 0),
         "never_bound": (never, 0),
     }
@@ -671,7 +672,8 @@ def apiserver_views(report: dict, rounds: list[dict]):
     nodes, and the pods earlier rounds decided but whose bindings were
     not yet observable (the pipelined loop POSTs a round's bindings
     while the next round solves), on the machines decided. A pod's wait
-    is the number of earlier rounds it was pending in."""
+    is the number of earlier rounds it was pending in. Machines carry the
+    labels and taints the child served, pending pods their constraints."""
     lists = report["lists"]
     list_t = np.asarray([t for t, _rv in lists])
     keys = [row[0] for row in report["pods"]]
@@ -682,10 +684,13 @@ def apiserver_views(report: dict, rounds: list[dict]):
         return np.asarray([inf if row[j] is None else row[j]
                            for row in report["pods"]], np.int64)
     added, bound, deleted = col(10), col(11), col(12)
-    machines = [(name, rack, cap) for name, rack, cap in report["nodes"]]
+    machines = [(name, rack, cap) for name, rack, cap, *_ in report["nodes"]]
+    labels = {row[0]: row[3] for row in report["nodes"] if row[3]}
+    taints = {row[0]: row[4] for row in report["nodes"] if row[4]}
     midx = {m: i for i, (m, _r, _c) in enumerate(machines)}
     node = np.asarray([midx.get(row[4], -1) for row in report["pods"]])
     prefs = {row[0]: row[9] for row in report["pods"]}
+    cons = {row[0]: row[13] for row in report["pods"] if row[13]}
     decided = np.full(len(keys), -1)      # machine an earlier round chose
     wait = np.zeros(len(keys), np.int64)
     for r in rounds:
@@ -708,7 +713,9 @@ def apiserver_views(report: dict, rounds: list[dict]):
                          for m, c in enumerate(count) if c},
                 pending=pending,
                 waits={k: int(wait[index[k]]) for k in pending},
-                prefs={k: prefs[k] for k in pending})
+                prefs={k: prefs[k] for k in pending},
+                labels=labels, taints=taints,
+                constraints={k: cons[k] for k in pending if k in cons})
             wait[pend] += 1
         for k, m in r["bindings"].items():
             j = index.get(k)

@@ -85,12 +85,12 @@ def test_a_watch_observed_cell_is_refused(tmp_path):
 
     root = copy_benchmark(tmp_path)
     spec = json.loads((root / "BENCHMARK.json").read_text())
-    spec["configs"].append({"name": "octopus_5k", "source": "x",
+    spec["configs"].append({"name": "octopus_5k_watch", "source": "x",
                             "file": "benchmark/configs/octopus_5k.json",
                             "reduced": [], "why": "x"})
-    spec["workloads"].append({"name": "octopus_5k.backlog",
-                              "config": "octopus_5k", "traffic": "backlog",
-                              "chips": 1, "why": "x"})
+    spec["workloads"].append({"name": "octopus_5k_watch.backlog",
+                              "config": "octopus_5k_watch",
+                              "traffic": "backlog", "chips": 1, "why": "x"})
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
     with pytest.raises(run.BenchError, match="watch"):
-        run.load_cell(root, "octopus_5k.backlog")
+        run.load_cell(root, "octopus_5k_watch.backlog")

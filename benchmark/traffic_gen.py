@@ -23,7 +23,17 @@ Two kinds of traffic, chosen by the file's ``kind``:
 - ``waves``: a closed loop. A wave of ``wave_pods`` pods is created at
   once, and at the same moment the ``wave_pods`` oldest running pods are
   deleted; the next wave comes when every pod of the previous one is
-  bound.
+  bound. A wave is one job, or ``wave_jobs`` jobs of equal size.
+
+Placement constraints are optional (``constraints.py`` has their
+semantics): ``cluster.zones`` puts each rack (each node, where there are
+no racks) in one of that many zones, interleaved as racks are;
+``cluster.node_pools`` labels and taints the nodes, each pool on its
+``share`` of them; ``pods.constraints`` gives each job one of its
+entries with probability ``share`` (no constraint for the rest), as a
+Deployment or Job template does for all its pods. Their draws come from
+streams of their own, so a configuration without them draws exactly
+what it did before they existed.
 """
 
 from __future__ import annotations
@@ -33,6 +43,15 @@ import json
 import pathlib
 
 import numpy as np
+
+import constraints
+
+ZONE_LABEL = "topology.kubernetes.io/zone"
+# rng_for streams: 1 nodes, 2 initial placement, 3 open jobs, 4 and 6 pod
+# factories, 5 lifetimes, 7 node pools, 8 (with the job's name) a job's
+# constraint
+POOL_STREAM = 7
+CONSTRAINT_STREAM = 8
 
 
 def load_json(path: str | pathlib.Path) -> dict:
@@ -83,6 +102,15 @@ class Node:
     cpu_milli: int
     memory_kb: int
     pods: int
+    # labels besides the rack's, and taints: empty unless configured
+    labels: dict[str, str] = dataclasses.field(default_factory=dict,
+                                               hash=False)
+    taints: tuple[dict, ...] = dataclasses.field(default=(), hash=False)
+
+    @property
+    def all_labels(self) -> dict[str, str]:
+        """Every label the node object carries."""
+        return {**({"rack": self.rack} if self.rack else {}), **self.labels}
 
 
 @dataclasses.dataclass
@@ -92,6 +120,7 @@ class PodSpec:
     cpu_milli: int
     memory_kb: int
     prefs: dict[str, int]
+    constraint: dict | None = None    # constraints.py's shape
 
 
 def make_nodes(cfg: dict, seed: int) -> list[Node]:
@@ -102,16 +131,45 @@ def make_nodes(cfg: dict, seed: int) -> list[Node]:
     n_racks = max(1, -(-n // per_rack)) if per_rack else 0
     cpu = rng.choice(np.asarray(c["node_cpu_milli"]), size=n)
     mem = rng.choice(np.asarray(c["node_memory_kb"]), size=n)
-    return [
-        Node(
+    pool = node_pools(c, n, seed)
+    pools = c.get("node_pools", [])
+    zones = int(c.get("zones", 0))
+    nodes = []
+    for i in range(n):
+        labels: dict[str, str] = {}
+        if zones:   # a rack lies in one zone
+            z = (i % n_racks if n_racks else i) % zones
+            labels[ZONE_LABEL] = f"zone-{z}"
+        taints: tuple[dict, ...] = ()
+        if pool[i] >= 0:
+            labels.update(pools[pool[i]].get("labels", {}))
+            taints = tuple(pools[pool[i]].get("taints", []))
+        nodes.append(Node(
             name=f"m{i:05d}",
             rack=f"rack{i % n_racks:03d}" if n_racks else "",
             cpu_milli=int(cpu[i]),
             memory_kb=int(mem[i]),
             pods=int(c["max_tasks_per_pu"]),
-        )
-        for i in range(n)
-    ]
+            labels=labels,
+            taints=taints,
+        ))
+    return nodes
+
+
+def node_pools(cluster: dict, n: int, seed: int) -> np.ndarray:
+    """Each node's pool (-1: none): pool k on round(share_k x n) nodes
+    (largest remainders), the nodes picked by the seed."""
+    pools = cluster.get("node_pools", [])
+    out = np.full(n, -1)
+    if not pools:
+        return out
+    want = np.asarray([float(p["share"]) for p in pools]) * n
+    count = np.floor(want).astype(np.int64)
+    left = min(n, int(round(want.sum()))) - int(count.sum())
+    count[np.argsort(-(want - count), kind="stable")[:max(left, 0)]] += 1
+    order = rng_for(seed, POOL_STREAM).permutation(n)
+    out[order[:int(count.sum())]] = np.repeat(np.arange(len(pools)), count)
+    return out
 
 
 class PodFactory:
@@ -120,7 +178,9 @@ class PodFactory:
     def __init__(self, cfg: dict, nodes: list[Node], seed: int,
                  stream: int):
         self.p = cfg["pods"]
+        self.seed = seed
         self.rng = rng_for(seed, stream)
+        self.job_constraint: dict[str, dict | None] = {}
         racks = sorted({n.rack for n in nodes if n.rack})
         self.racks = racks
         by_rack: dict[str, list[str]] = {r: [] for r in racks}
@@ -129,8 +189,27 @@ class PodFactory:
                 by_rack[n.rack].append(n.name)
         self.by_rack = by_rack
 
+    def constraint_of(self, job: str) -> dict | None:
+        """The job's constraint, drawn from the seed and the job's name
+        alone, so it is the same whichever factory or thread asks."""
+        table = self.p.get("constraints")
+        if not table:
+            return None
+        if job not in self.job_constraint:
+            u = np.random.default_rng([int(self.seed) % (1 << 64),
+                                       CONSTRAINT_STREAM,
+                                       *job.encode()]).random()
+            edges = np.cumsum([float(c["share"]) for c in table])
+            k = int(np.searchsorted(edges, u, side="right"))
+            self.job_constraint[job] = None if k == len(table) else {
+                key: table[k][key] for key in constraints.KEYS
+                if key in table[k]}
+        return self.job_constraint[job]
+
     def make(self, name: str, job: str, with_prefs: bool = True
              ) -> PodSpec:
+        """A pod of ``job``; ``with_prefs`` False: a pod running from
+        before the run, with no preferences and no constraint."""
         p, rng = self.p, self.rng
         cpu = int(rng.choice(np.asarray(p["cpu_milli"])))
         mem = int(rng.choice(np.asarray(p["memory_kb"])))
@@ -149,7 +228,8 @@ class PodFactory:
             if rng.random() < float(p.get("rack_pref_share", 0)):
                 lo, hi = p["rack_pref_weight"]
                 prefs[home] = int(rng.integers(lo, hi))
-        return PodSpec(name, job, cpu, mem, prefs)
+        return PodSpec(name, job, cpu, mem, prefs,
+                       self.constraint_of(job) if with_prefs else None)
 
 
 def initial_placement(nodes: list[Node], n_running: int, seed: int
@@ -231,3 +311,4 @@ def check_config(cfg: dict) -> None:
             raise ValueError(f"configuration lacks cluster.{key}")
     if int(c["nodes"]) < 1:
         raise ValueError("cluster.nodes must be a positive count")
+    constraints.validate(cfg)
